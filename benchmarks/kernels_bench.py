@@ -188,7 +188,6 @@ def sweep_dist(out_path=None, shards=(4, 8), ks=(1, 8, 16, 32), reps=16):
     mesh, and every path is pinned against the coo result.  Needs a
     multi-device platform: ``make bench-dist`` forces 8 host devices.
     """
-    from repro.compat import make_mesh
     from repro.graphs import sbm_graph_sparse
     from repro.grblas import HALO_FALLBACK_FRAC, make_row_partition
 
@@ -232,7 +231,7 @@ def sweep_dist(out_path=None, shards=(4, 8), ks=(1, 8, 16, 32), reps=16):
     for name, W, aligned in graphs:
         entry = {"graph": name, "n": W.n_rows, "nnz": W.nnz, "entries": []}
         for S in shards:
-            mesh = make_mesh((int(S),), ("data",))
+            mesh = jax.make_mesh((int(S),), ("data",))
             d = Descriptor(backend="dist", mesh=mesh)
             ds = Descriptor(backend="dist_sellcs", mesh=mesh)
             for placement in ("aligned", "shuffled"):

@@ -47,7 +47,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, List, Optional
 
-import jax
 import jax.numpy as jnp
 
 from repro.obs import metrics as _obs_metrics
@@ -299,12 +298,11 @@ def backend_bakes_ring_params(cfg, W, probes) -> bool:
     """Would the backend serving these (ring, X-probe) combinations bake
     the ring's (p, eps) into a Pallas kernel as static arguments?  Then
     p cannot be a tracer and the driver's memo key must include it
-    (trace per level, cached across runs).  Pallas paths are only taken
-    on TPU or under interpret; everywhere else the jnp paths keep the
+    (trace per level, cached across runs).  Each backend answers for
+    the implementation it actually runs under this descriptor on this
+    platform (``Backend.static_ring_params``); the jnp paths keep the
     traced-p single trace.  ``probes`` is a list of (ring, X) with X a
     ShapeDtypeStruct or a tuple of them (pair rings)."""
-    if not (cfg.interpret or jax.default_backend() == "tpu"):
-        return False
     from repro.grblas import backends as _backends
 
     desc = cfg.descriptor()
@@ -313,6 +311,6 @@ def backend_bakes_ring_params(cfg, W, probes) -> bool:
             be = _backends.select_backend(W, X, ring, desc)
         except _backends.BackendUnavailableError:
             continue    # validate_backend already raised for real runs
-        if be.static_ring_params:
+        if be.static_ring_params(desc):
             return True
     return False

@@ -29,8 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def quantize_int8(x: jnp.ndarray):
     """Symmetric per-tensor int8 quantisation.
@@ -69,8 +67,8 @@ def _pmean_tree(tree, mesh, axis):
     def body(t):
         return jax.tree.map(lambda v: jax.lax.psum(v, axis) / size, t)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                   check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                       check_vma=False)
     return fn(tree)
 
 

@@ -20,6 +20,7 @@ Registered backends (priority: lower = preferred under "auto"):
   sellcs       SELL-C-σ        padded-reducer rings (incl.  19   12
                                multivals) + plap edge kinds
   ell          padded ELL      rings with a padded reducer  20   20
+                               + plap edge kinds
   coo          COO (always)    any ring, transpose, multivals 30 30
   spgemm       COO (always)    reals, X a SparseMatrix      25   25
 
@@ -39,7 +40,9 @@ point (DESIGN.md §5).  Naming backend="sellcs" explicitly always runs.
 
 The Pallas kernels rank first on TPU and last on CPU: their jnp
 reference paths exist everywhere (and run under ``desc.interpret``),
-but on CPU the gather/segment formulations win.  ``dist`` outranks
+but on CPU the gather/segment formulations win.  The SELL-C-σ kernels
+are the exception: the TPU compiler refuses them, so "sellcs" runs its
+jnp/XLA path on the chip (``sellcs_uses_pallas``).  ``dist`` outranks
 everything once a mesh is supplied — the caller asked for sharding.
 
 New hardware or layouts are one ``register_backend`` call, not a fifth
@@ -73,18 +76,19 @@ class Backend:
     execute: Callable       # (A, X, ring, desc) -> jnp.ndarray
     cpu_priority: int       # auto-selection rank off-TPU (lower wins)
     tpu_priority: int       # auto-selection rank on TPU
-    # True when this backend's Pallas path (taken on TPU or under
-    # desc.interpret) bakes the ring's (p, eps) params into the kernel
-    # as static arguments — callers that jit over a *traced* p (the
-    # psc continuation loop) must concretize p before reaching it.
-    static_ring_params: bool = False
+    # (desc) -> True when the implementation this backend runs under
+    # ``desc`` on this platform is a Pallas kernel that bakes the ring's
+    # (p, eps) params in as static arguments — callers that jit over a
+    # *traced* p (the psc continuation loop) must concretize p first.
+    static_ring_params: Callable = lambda desc: False
 
 
 _REGISTRY: Dict[str, Backend] = {}
 
 
 def register_backend(name: str, *, cpu_priority: int, tpu_priority: int,
-                     supports: Callable, static_ring_params: bool = False):
+                     supports: Callable,
+                     static_ring_params: Callable = lambda desc: False):
     """Decorator: register ``fn`` as the execute hook of backend ``name``."""
 
     def deco(fn):
@@ -215,22 +219,46 @@ def _coo_execute(A, X, ring, desc):
 
 def _ell_supports(A, X, ring, desc):
     """Padded-ELL is only sound for rings whose pad entries (col=row,
-    val=0) contribute the add-identity — exactly the rings with a
-    registered ``padded`` fast path (semiring.register_ring_fast_paths)."""
-    return (isinstance(A, SparseMatrix)
-            and A.ell_cols is not None
-            and A.vals.ndim == 1
-            and isinstance(ring, Semiring)
-            and not isinstance(ring, (EdgeSemiring, PairEdgeSemiring))
-            and not _is_pair(X) and not _is_sparse(X)
-            and not desc.transpose
+    val=0) contribute the add-identity — the rings with a registered
+    ``padded`` fast path (semiring.register_ring_fast_paths), and the
+    plap edge kinds, whose multiply annihilates on w=0 (the SELL-C-σ
+    gate below)."""
+    if not (isinstance(A, SparseMatrix) and A.ell_cols is not None
+            and A.vals.ndim == 1 and not _is_sparse(X)
+            and not desc.transpose):
+        return False
+    if isinstance(ring, PairEdgeSemiring):
+        return (ring.kind == "plap_hvp" and _square(A) and _is_pair(X)
+                and len(X) == 2 and getattr(X[0], "ndim", 0) == 2
+                and X[0].shape == X[1].shape)
+    if isinstance(ring, EdgeSemiring):
+        return (ring.kind == "plap_apply" and _square(A)
+                and getattr(X, "ndim", 0) == 2)
+    return (isinstance(ring, Semiring) and not _is_pair(X)
             and fast_paths(ring).padded is not None)
 
 
 @register_backend("ell", cpu_priority=20, tpu_priority=20,
                   supports=_ell_supports)
 def _ell_execute(A, X, ring, desc):
-    """Padded-ELL: gather (n, max_nnz[, k]) then fold along the pad axis."""
+    """Padded-ELL: gather (n, max_nnz[, k]) then fold along the pad axis.
+    The reals ring and the plap edge kinds fold slot by slot
+    (``sellcs_spmm.ref``: ELL is one SELL-C-σ run in row order)."""
+    from repro.kernels.sellcs_spmm.ref import (sellcs_plap_apply_ref,
+                                               sellcs_plap_hvp_ref, slot_sum)
+
+    if isinstance(ring, PairEdgeSemiring):
+        U, E = X
+        return sellcs_plap_hvp_ref(A.ell_cols, A.ell_vals, U, E, 0,
+                                   *ring.params)
+    if isinstance(ring, EdgeSemiring):
+        return sellcs_plap_apply_ref(A.ell_cols, A.ell_vals, X, 0,
+                                     *ring.params)
+    if ring.name == "reals_+x":
+        if X.ndim == 1:
+            return slot_sum(A.ell_cols, A.ell_vals, lambda c, v: v * X[c])
+        return slot_sum(A.ell_cols, A.ell_vals,
+                        lambda c, v: v[:, None] * X[c])
     gathered = X[A.ell_cols]                      # (n, m[, k])
     vals = A.ell_vals if X.ndim == 1 else A.ell_vals[..., None]
     contrib = ring.mul(vals, gathered)
@@ -272,12 +300,22 @@ def _sellcs_supports(A, X, ring, desc):
     return not _auto_defers_to_ell(A, X, ring, desc)
 
 
-def sellcs_run(A, X, ring, interpret: bool = False,
-               use_pallas: bool | None = None):
-    """SELL-C-σ SpMM with explicit path control (shared by the backend
-    and the benchmarks).  Permute the multivector once (σ-sort order),
-    run one gather+fold per width run — Pallas kernel (TPU / interpret)
-    or the jnp reference — and un-permute the output.
+def sellcs_uses_pallas(interpret: bool) -> bool:
+    """The one rule that picks the SELL-C-σ implementation: the Pallas
+    kernels run only in interpreter mode (the CPU numerics pin); on
+    every platform, the TPU included, the backend runs the jnp/XLA path.
+    The TPU compiler refuses the kernels: Mosaic cannot lower their
+    ``jnp.take`` sublane gather ("Shape mismatch in input, indices and
+    output"), and their whole-multivector VMEM block outgrows the chip's
+    fast memory at real n."""
+    return interpret
+
+
+def sellcs_run(A, X, ring, interpret: bool = False):
+    """SELL-C-σ SpMM (shared by the backend and the benchmarks).
+    Permute the multivector once (σ-sort order), run one gather+fold
+    per width run — Pallas kernel (``sellcs_uses_pallas``) or the jnp
+    reference — and un-permute the output.
 
     ``X`` is a multivector for plain/edge rings, a (U, Eta) pair for the
     "plap_hvp" kind.  (nnz, k) multivalues (with_vals) take the jnp path
@@ -287,8 +325,7 @@ def sellcs_run(A, X, ring, interpret: bool = False,
         sellcs_plap_hvp_pallas, sellcs_plap_hvp_ref,
         sellcs_spmm_pallas, sellcs_spmm_ref)
 
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu" or interpret
+    use_pallas = sellcs_uses_pallas(interpret)
     C = A.sell_c
     pair = _is_pair(X)
     one_d = False
@@ -336,10 +373,12 @@ def sellcs_run(A, X, ring, interpret: bool = False,
 
 
 @register_backend("sellcs", cpu_priority=19, tpu_priority=12,
-                  supports=_sellcs_supports, static_ring_params=True)
+                  supports=_sellcs_supports,
+                  static_ring_params=lambda desc: sellcs_uses_pallas(
+                      desc.interpret))
 def _sellcs_execute(A, X, ring, desc):
-    """Sliced-ELLPACK gather + ring fold over per-width runs; Pallas
-    kernel on TPU (or under ``desc.interpret``), vectorized jnp on CPU.
+    """Sliced-ELLPACK gather + ring fold over per-width runs; vectorized
+    jnp/XLA everywhere, Pallas kernel under ``desc.interpret``.
     The σ permutation is applied to the multivector on the way in and
     inverted on the way out — callers never observe it."""
     return sellcs_run(A, X, ring, interpret=desc.interpret)
@@ -450,8 +489,13 @@ def edge_pallas_run(A, X, ring, interpret: bool = False,
     return Y[: A.n_rows]
 
 
+def _edge_pallas_bakes(desc) -> bool:
+    return desc.interpret or jax.default_backend() == "tpu"
+
+
 @register_backend("edge_pallas", cpu_priority=61, tpu_priority=10,
-                  supports=_edge_pallas_supports, static_ring_params=True)
+                  supports=_edge_pallas_supports,
+                  static_ring_params=_edge_pallas_bakes)
 def _edge_pallas_execute(A, X, ring, desc):
     """Fused p-Laplacian edge-semiring kernels over BSR tiles.
 

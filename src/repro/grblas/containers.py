@@ -376,7 +376,12 @@ class SparseMatrix:
             m.sell_inv = self.sell_inv
             m.sell_cols = self.sell_cols
             m.sell_scatter = self.sell_scatter
-            m.sell_vals = tuple(vext[sc] for sc in self.sell_scatter)
+            # gather through the flattened (rows * w,) index: the TPU
+            # compiler takes minutes over a 2-D (rows, w) index array
+            # with rows between about 2^14 and 2^18, seconds over 1-D
+            m.sell_vals = tuple(
+                vext[sc.reshape(-1)].reshape(sc.shape + vals.shape[1:])
+                for sc in self.sell_scatter)
         return m
 
     def host_coo(self):

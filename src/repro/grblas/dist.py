@@ -41,10 +41,8 @@ from typing import Optional, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
-from repro.compat import shard_map
 from repro.grblas.containers import SparseMatrix
 from repro.obs import metrics as _obs_metrics
 from repro.obs import trace as _obs_trace
@@ -453,14 +451,21 @@ def _shard_mxm_impl(Ap, X, mesh, axis, ring, layout, S, R):
     else:
         raise ValueError(f"layout must be ell|sellcs, got {layout!r}")
 
-    out = out[: Ap.n_rows]                    # slice pads FIRST …
-    if Ap.inv_perm is not None:
-        out = out[Ap.inv_perm]                # … then un-permute
+    # drop the pad rows and undo the placement in one gather: inv_perm
+    # (or the identity) indexes only real rows.  The operand is sharded
+    # over ``axis``, so the gather names its (replicated) output
+    # sharding — jax refuses to guess one for a sharded operand.
+    rows = (Ap.inv_perm if Ap.inv_perm is not None
+            else np.arange(Ap.n_rows))
+    out = out.at[jnp.asarray(rows)].get(
+        out_sharding=NamedSharding(mesh, P()))
     return out[:, 0] if one_d else out
 
 
 def _shard_ell(Ap, X, mesh, axis, ring, edge, vec_spec, plan_spec,
                mat_spec, L):
+    from repro.kernels.sellcs_spmm.ref import slot_sum
+
     halo = Ap.mode == "halo"
 
     def local(ell_cols, ell_vals, x_local, *plan):
@@ -470,25 +475,25 @@ def _shard_ell(Ap, X, mesh, axis, ring, edge, vec_spec, plan_spec,
             x_src = _exchange(Ap, x_local, plan[0][0], axis)
         else:
             x_src = jax.lax.all_gather(x_local, axis, axis=0, tiled=True)
-        gathered = x_src[ell_cols]                        # (R, M, k)
-        vals = ell_vals[..., None]
+        # slot-by-slot (R, k) fold (sellcs_spmm.ref.slot_sum).  Pad
+        # slots carry val=0 and every ring _dist_supports admits has the
+        # reals base and annihilates zero contributions.
         if edge:
             # x_i is this shard's own rows — x_local directly (edge
             # rings are square-gated, so the row and column spaces and
             # their paddings coincide)
-            contrib = ring.edge_mul(vals, gathered, x_local[:, None, :])
+            term = lambda c, v: ring.edge_mul(v[:, None], x_src[c], x_local)
         else:
-            contrib = ring.mul(vals, gathered)
-        # pscheck: disable=pad-fold (pad slots carry val=0 and every ring the dist backends admit via _dist_supports annihilates zero contributions, so the width-axis fold is pad-sound by the capability gate)
-        return jnp.sum(contrib, axis=1)
+            term = lambda c, v: ring.mul(v[:, None], x_src[c])
+        return slot_sum(ell_cols, ell_vals, term)
 
     args = [Ap.ell_cols, Ap.ell_vals, X]
     specs = [mat_spec, mat_spec, vec_spec]
     if halo:
         args.append(Ap.send_idx)
         specs.append(plan_spec)
-    fn = shard_map(local, mesh=mesh, in_specs=tuple(specs),
-                   out_specs=vec_spec, check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                       out_specs=vec_spec, check_vma=False)
     return fn(*args)
 
 
@@ -529,8 +534,8 @@ def _shard_sellcs(Ap, X, mesh, axis, ring, edge, vec_spec, plan_spec):
         specs.append(plan_spec)
     args += list(sell.run_cols) + list(sell.run_vals) + list(sell.run_own)
     specs += ([P(axis, None, None)] * 2 * n_runs + [plan_spec] * n_runs)
-    fn = shard_map(local, mesh=mesh, in_specs=tuple(specs),
-                   out_specs=vec_spec, check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                       out_specs=vec_spec, check_vma=False)
     return fn(*args)
 
 
@@ -583,4 +588,4 @@ def device_mesh(axis: str = "data", n_shards: Optional[int] = None):
     """
     init_distributed()
     n = n_shards if n_shards is not None else len(jax.devices())
-    return compat.make_mesh((n,), (axis,))
+    return jax.make_mesh((n,), (axis,))

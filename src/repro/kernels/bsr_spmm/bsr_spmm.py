@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _kernel(indices_ref, row_ids_ref, blocks_ref, x_ref, y_ref):
     b = pl.program_id(0)
@@ -68,6 +66,6 @@ def bsr_spmm_pallas(blocks: jnp.ndarray, indices: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_row_blocks * bs, k), X.dtype),
         interpret=interpret,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),  # revisits output: sequential
     )(indices, row_ids, blocks, X)

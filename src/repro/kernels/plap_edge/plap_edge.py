@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 from repro.core import phi as PHI
 
 
@@ -86,7 +84,7 @@ def plap_apply_pallas(blocks, indices, row_ids, X, n_row_blocks,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_row_blocks * bs, k), X.dtype),
         interpret=interpret,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(indices, row_ids, blocks, X, X)
 
@@ -106,6 +104,6 @@ def plap_hvp_pallas(blocks, indices, row_ids, U, Eta, n_row_blocks,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_row_blocks * bs, k), U.dtype),
         interpret=interpret,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(indices, row_ids, blocks, U, U, Eta, Eta)

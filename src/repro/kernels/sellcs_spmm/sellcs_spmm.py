@@ -20,6 +20,12 @@ VMEM (~0.5 MB at n=32k, k=4); beyond that the production path is the
 same kernel over row-partitioned shards (the "dist" backend composes),
 or an HBM-resident Xp with per-slice DMA gathers.
 
+The TPU compiler refuses these kernels at every size: Mosaic cannot
+lower the ``jnp.take`` sublane gather ("Shape mismatch in input,
+indices and output").  So they run only in interpret mode, as the
+numerics pin of the jnp path the "sellcs" backend runs on the chip
+(``grblas.backends.sellcs_uses_pallas``).
+
 Three ring kinds, mirroring the ELL/edge capability split:
 
     sellcs_spmm_pallas       y_i = sum_j a_ij x_j            (reals ring)
@@ -37,8 +43,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.core import phi as PHI
 
 
@@ -86,7 +92,7 @@ def _call(kernel, n_slices, in_specs, out_spec, rows_r, k, dtype, interpret,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((rows_r, k), dtype),
         interpret=interpret,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(*args)
 

@@ -1,12 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=512")
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell
 and extract memory/cost/roofline artifacts.
 
-THE two lines above must run before any other import (jax locks the
-device count on first init).
+``main()`` asks XLA for 512 host devices before anything touches a
+device (jax locks the device count when its backend first starts).
+Importing this module changes nothing.
 
 Usage:
   python -m repro.launch.dryrun --arch gemma-2b --shape train_4k --mesh single
@@ -17,6 +14,7 @@ memory_analysis, cost_analysis, collective breakdown and roofline terms.
 """
 import argparse
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -27,6 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.launch.shapes import SHAPES, input_specs, cell_status
 from repro.launch import hlo_analysis as HA
@@ -239,7 +238,13 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path,
     return result
 
 
+HOST_DEVICES_FLAG = "--xla_force_host_platform_device_count=512"
+
+
 def main():
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " "
+                               + HOST_DEVICES_FLAG).strip()
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--shape", choices=list(SHAPES))

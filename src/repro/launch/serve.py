@@ -11,12 +11,14 @@ import time
 import numpy as np
 import jax
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs import ARCH_IDS, get_config, get_reduced_config
 from repro.models import model as M
 from repro.serve import ServeEngine, GenerationConfig
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="gemma-2b")
     ap.add_argument("--reduced", action="store_true")

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models.config import ArchConfig
 from repro.models import layers as L
 from repro.models.layers import PAb
@@ -208,7 +207,7 @@ def moe_block(cfg: ArchConfig, params, x, mesh=None
         shared_args = tuple(shared[k] for k in names)
         shared_specs = tuple(P("model", None) if k == "down"
                              else P(None, "model") for k in names)
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec, down_spec)
         + shared_specs,
@@ -306,7 +305,7 @@ def _a2a_moe_block(cfg, params, x, mesh, model_n, batch_axes, B_local,
         aux = jax.lax.pmean(aux, "model")
         return y.reshape(x_l.shape), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec, w_spec)
         + shared_specs,

@@ -426,14 +426,14 @@ class Telemetry:
 # ------------------------------------------------------------------ helpers
 
 def under_trace(*values) -> bool:
-    """True when called during jit tracing (wall-clock spans would time
-    the *trace*, not the run — instrument sites degrade to dispatch
-    counters there).  The probe values are a fallback for jax versions
-    without ``trace_state_clean``."""
-    try:
-        return not jax.core.trace_state_clean()
-    except Exception:
-        return any(isinstance(v, jax.core.Tracer) for v in values)
+    """True when called during a jax transformation (jit, vmap,
+    shard_map, grad): wall-clock spans would time the *trace*, not the
+    run, so instrument sites degrade to dispatch counters there.  Asks
+    jax's trace context, so a call inside ``jit`` whose operands are all
+    closed-over concrete arrays still counts as traced; a tracer among
+    ``values`` also counts."""
+    return (not jax.core.trace_ctx.is_top_level()
+            or any(isinstance(v, jax.core.Tracer) for v in values))
 
 
 def roofline_summary(spans, peak_gbs: Optional[float] = None

@@ -31,8 +31,7 @@ import jax
 
 
 def _flatten_with_paths(tree):
-    from repro.compat import tree_flatten_with_path
-    flat, treedef = tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     def pstr(path):
         parts = []
         for p in path:

@@ -51,6 +51,7 @@ REALS_DESCRIPTORS = [
 ]
 
 EDGE_DESCRIPTORS = [
+    Descriptor(backend="ell"),                         # padded-row slot folds
     Descriptor(backend="edge_pallas"),
     Descriptor(backend="edge_pallas", interpret=True),
     Descriptor(backend="sellcs"),

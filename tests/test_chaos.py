@@ -354,14 +354,13 @@ _HALO_SCRIPT = textwrap.dedent("""
     N = int(os.environ["DIST_TEST_DEVICES"])
     os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={N}"
     import numpy as np
-    import jax.numpy as jnp
-    from repro.compat import make_mesh
+    import jax, jax.numpy as jnp
     from repro.graphs import sbm_graph
     from repro.grblas import Descriptor, make_row_partition, mxm
     from repro.testing import halo_corruption
 
     S = 4
-    mesh = make_mesh((S,), ("data",))
+    mesh = jax.make_mesh((S,), ("data",))
     d = Descriptor(backend="dist", mesh=mesh)
     W, truth = sbm_graph([128] * S, 0.06, 0.002, seed=0)
     X = jnp.asarray(np.random.default_rng(0).standard_normal(

@@ -19,9 +19,9 @@ from pathlib import Path
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
-from repro.compat import make_mesh
 from repro.graphs import delaunay_graph, sbm_graph
 from repro.grblas import (Descriptor, HALO_FALLBACK_FRAC, SparseMatrix,
                           available_backends, make_row_partition, mxm)
@@ -103,7 +103,7 @@ def test_edge_ring_square_gate_routes_rectangular_away_from_dist():
     n = W.n_rows
     r, c, v = W.host_coo()
     Wrect = SparseMatrix.from_coo(r, c, v, (n, n + 32), build_ell=True)
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",))
     d = Descriptor(mesh=mesh)
     ring = plap_edge_semiring(1.5, eps=1e-8)
     X = jnp.ones((n + 32, 2), jnp.float32)
@@ -131,7 +131,7 @@ def test_assignment_requires_square():
 
 def test_dist_sellcs_requires_layout_on_prebuilt_partition():
     W = _graph()
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",))
     Ap = make_row_partition(W, 1)               # no sellcs slicing
     X = jnp.ones((W.n_rows, 2), jnp.float32)
     d = Descriptor(backend="dist_sellcs", mesh=mesh)
@@ -169,7 +169,6 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={N}"
     import numpy as np
     import jax, jax.numpy as jnp
-    from repro.compat import make_mesh
     from repro.graphs import sbm_graph
     from repro.grblas import (Descriptor, device_mesh, init_distributed,
                               make_row_partition, mxm)
@@ -181,7 +180,7 @@ SCRIPT = textwrap.dedent("""
     mesh_all = device_mesh()
     assert int(mesh_all.shape["data"]) == N
     S = 4
-    mesh = make_mesh((S,), ("data",))        # 4-shard submesh
+    mesh = jax.make_mesh((S,), ("data",))        # 4-shard submesh
     d = Descriptor(backend="dist", mesh=mesh)
     ds = Descriptor(backend="dist_sellcs", mesh=mesh)
     rng = np.random.default_rng(0)
@@ -217,7 +216,7 @@ SCRIPT = textwrap.dedent("""
     assert Ap2.mode == "halo"
     wb2 = Ap2.wire_bytes(k=16)
     assert wb2["halo"] < wb2["gather"], wb2
-    d2 = Descriptor(backend="dist", mesh=make_mesh((2,), ("data",)))
+    d2 = Descriptor(backend="dist", mesh=jax.make_mesh((2,), ("data",)))
     X2 = jnp.asarray(rng.standard_normal((W2.n_rows, 16)), jnp.float32)
     np.testing.assert_allclose(np.asarray(mxm(Ap2, X2, desc=d2)),
                                np.asarray(mxm(W2, X2)),

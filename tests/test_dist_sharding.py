@@ -18,11 +18,11 @@ from jax.sharding import AbstractMesh
 def single_pod():
     # shape-only stand-in for make_production_mesh(multi_pod=False):
     # resolve_spec reads mesh.shape, never device placement
-    return AbstractMesh((("data", 16), ("model", 16)))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def multi_pod():
-    return AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+    return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 # ------------------------------------------------------------ resolve_spec

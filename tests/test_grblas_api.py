@@ -288,11 +288,13 @@ def test_psc_backend_validated_up_front():
     from repro.graphs import ring_of_cliques
 
     W, _ = ring_of_cliques(3, 6)
-    for bad in ("ell", "bsr_pallas", "dist"):
+    for bad in ("bsr_pallas", "edge_pallas", "dist"):
         with pytest.raises(BackendUnavailableError):
             p_spectral_cluster(W, PSCConfig(k=2, backend=bad))
-    # "coo" passes validation (full run exercised elsewhere)
-    PSCConfig(k=2, backend="coo").validate_backend(W)
+    # "coo" and "ell" (plap edge kinds on the padded rows) pass
+    # validation (full runs exercised elsewhere)
+    for good in ("coo", "ell"):
+        PSCConfig(k=2, backend=good).validate_backend(W)
 
 
 def test_dist_rejects_traced_matrix_with_clear_error():

@@ -212,7 +212,8 @@ def test_scf_continuation_hits_one_trace():
 # --------------------------------------------------------- property checks
 
 @given(seed=st.integers(min_value=0, max_value=10_000),
-       p=st.floats(min_value=1.05, max_value=2.0, width=32))
+       p=st.floats(min_value=float(np.float32(1.05)), max_value=2.0,
+                   width=32))
 @settings(max_examples=8, deadline=None)
 def test_property_scf_driver_well_posed(seed, p):
     """Over random planted patterns and random p: the SCF driver returns

@@ -87,7 +87,6 @@ def bench_stream(n_requests=24):
         "compiles_per_bucket": {str(k): v for k, v in per_key.items()},
         "engine_traces": eng.stats.traces,
         "n_batches": eng.stats.n_batches,
-        "graphs_per_s": round(eng.stats.graphs_per_s, 2),
         "mean_rcut": round(float(np.mean([r.rcut for r in results])), 4),
         "span_s": {name: round(sec, 4)
                    for name, sec in sorted(tr.by_name().items())},

@@ -139,14 +139,11 @@ def _execute_observed(be, A, X, ring, desc, tr):
     """Dispatch accounting when tracing is on.  Inside a jit trace the
     op runs once per *compile*, so wall-clock spans would time the
     tracer — record the dispatch decision (backend, ring kind) as an
-    instant + counter instead.  Eager calls get a fenced span carrying
-    shapes, nnz, and the byte model (→ achieved GB/s via
+    instant instead.  Eager calls get a fenced span carrying shapes,
+    nnz, and the byte model (→ achieved GB/s via
     obs.trace.roofline_summary)."""
     kind = _ring_kind(ring)
     if _obs_trace.under_trace(X[0] if isinstance(X, tuple) else X):
-        _obs_metrics.DEFAULT.counter("grblas_dispatch_total",
-                                     backend=be.name, ring=kind,
-                                     ctx="traced").inc()
         tr.instant("grblas.dispatch", backend=be.name, ring=kind,
                    traced=True)
         return be.execute(A, X, ring, desc)
@@ -157,9 +154,6 @@ def _execute_observed(be, A, X, ring, desc, tr):
         Y = be.execute(A, X, ring, desc)
         sp.fence(Y)
         sp.set(bytes=_traffic_bytes(A, k))
-    _obs_metrics.DEFAULT.counter("grblas_dispatch_total", backend=be.name,
-                                 ring=kind, ctx="eager").inc()
-    _obs_metrics.DEFAULT.counter("grblas_nnz_total", backend=be.name).inc(nnz)
     return Y
 
 

@@ -22,6 +22,11 @@ Chrome/Perfetto trace-event JSON or JSONL.  Three design rules:
     monotonic clock for deterministic tests (export round-trips assert
     exact timestamps, not sleeps).
 
+With ``TraceConfig.annotate`` every span also opens a
+``jax.profiler.TraceAnnotation`` of its bare name, so a profiler session
+running beside the tracer shows the program's spans on the host plane,
+on the profiler's own clock, nested as they were opened.
+
 The buffer is bounded (``TraceConfig.capacity``): when full, new spans
 are counted in ``Tracer.dropped`` instead of growing without limit — a
 serve engine left tracing for a week degrades to counters, it does not
@@ -56,6 +61,7 @@ class TraceConfig:
     capacity: int = 65536        # span+event buffer bound (drop past it)
     fence: bool = True           # block_until_ready at span fences
     clock: Optional[Callable[[], float]] = None   # None = time.perf_counter
+    annotate: bool = False       # mirror spans as profiler TraceAnnotations
 
 
 class Span:
@@ -163,7 +169,9 @@ class Tracer:
         self._clock = cfg.clock if cfg.clock is not None else time.perf_counter
         self._fence = cfg.fence
         self._capacity = int(cfg.capacity)
+        self._annotate = cfg.annotate
         self._stack: List[Span] = []
+        self._annotations: list = []    # open annotations, one per _stack entry
         self._seq = itertools.count(1)
         self.spans: List[Span] = []     # finished spans, exit order
         self.events: List[dict] = []    # instant events
@@ -193,16 +201,22 @@ class Tracer:
         sp.parent = self._stack[-1].sid if self._stack else None
         sp.depth = len(self._stack)
         self._stack.append(sp)
+        if self._annotate:
+            note = jax.profiler.TraceAnnotation(sp.name)
+            note.__enter__()
+            self._annotations.append(note)
         sp.t0 = self._clock() - self.t_start
+
+    def _pop(self) -> Span:
+        if self._annotate:
+            self._annotations.pop().__exit__(None, None, None)
+        return self._stack.pop()
 
     def _close(self, sp: Span) -> None:
         sp.dur = (self._clock() - self.t_start) - sp.t0
-        if self._stack and self._stack[-1] is sp:
-            self._stack.pop()
-        elif sp in self._stack:         # mis-nested exit: drop descendants
-            while self._stack and self._stack[-1] is not sp:
-                self._stack.pop()
-            self._stack.pop()
+        if sp in self._stack:           # a mis-nested exit drops descendants
+            while self._pop() is not sp:
+                pass
         if len(self.spans) >= self._capacity:
             self.dropped += 1
             return

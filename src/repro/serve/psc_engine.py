@@ -96,7 +96,7 @@ class ServeStats:
     cache_tier: Optional[str]    # None | "exact" | "pattern"
     bucket: Optional[tuple]      # BucketSpec key (bucket lane only)
     batch_size: int
-    queue_s: float
+    queue_s: float               # admission to launch (to failure, if failed)
     solve_s: float
     trace_new: bool              # this request's batch compiled a new trace
     p_final: float
@@ -105,6 +105,7 @@ class ServeStats:
     retries: int = 0             # churn-path retry count before success
     failure_kind: Optional[str] = None   # taxonomy key (failed requests)
     error: Optional[str] = None          # human-readable failure detail
+    finish_s: float = 0.0        # this request's own stage 3 (0 if failed)
 
 
 @dataclasses.dataclass
@@ -287,8 +288,8 @@ class EngineStats:
     underlying monotonic counter — so call sites and external readers
     are unchanged.  ``n_failed`` / ``failures`` both derive from the
     single labeled ``serve_failed_total`` family and can never
-    disagree.  ``solve_s`` / ``graphs_per_s`` stay plain floats (they
-    are derived timings, not monotonic counts).
+    disagree.  ``solve_s`` stays a plain float (a derived timing, not a
+    monotonic count).
     """
 
     # attribute -> counter family backing it
@@ -309,7 +310,6 @@ class EngineStats:
         self.registry = registry if registry is not None \
             else _obs_metrics.MetricsRegistry()
         self.solve_s = 0.0
-        self.graphs_per_s = 0.0
 
     def record_failure(self, kind: str) -> None:
         """The one write path for the failure taxonomy."""
@@ -332,7 +332,6 @@ class EngineStats:
                for name in ("n_requests", "n_results", "n_batches",
                             "n_solo", "n_churn", "traces")}
         out["solve_s"] = self.solve_s
-        out["graphs_per_s"] = self.graphs_per_s
         for name in ("n_failed", "n_degraded", "n_retried",
                      "n_quarantined", "n_quarantine_splits"):
             out[name] = getattr(self, name)
@@ -436,9 +435,11 @@ class ClusterServeEngine:
         counters + warm-cache counters + queue/occupancy instruments)."""
         return self.metrics.exposition()
 
+    def _queue_depth(self) -> int:
+        return sum(len(q) for q in self._buckets.values()) + len(self._solo)
+
     def _note_queue(self) -> None:
-        depth = sum(len(q) for q in self._buckets.values()) + len(self._solo)
-        self.metrics.gauge("serve_queue_depth").set(depth)
+        self.metrics.gauge("serve_queue_depth").set(self._queue_depth())
 
     # ------------------------------------------------------------ admission
 
@@ -525,21 +526,31 @@ class ClusterServeEngine:
     def poll(self, now: Optional[float] = None) -> Dict[int, ServeResult]:
         """Launch every due batch (bucket full, or oldest request past
         the max-wait deadline) and all solo requests; return results
-        completed so far (cumulative)."""
+        completed so far (cumulative).  A poll that launches anything is
+        one ``serve.poll`` span; one that launches nothing records
+        none."""
         now = time.monotonic() if now is None else now
+        depth = self._queue_depth()
         self._apply_deadlines(now)
+        due: List[List[_Pending]] = []
         for bkey in list(self._buckets):
             q = self._buckets[bkey]
             while q and (len(q) >= self.max_batch
                          or now - q[0].arrival >= self.max_wait_s):
-                take, self._buckets[bkey] = q[:self.max_batch], \
-                    q[self.max_batch:]
-                q = self._buckets[bkey]
-                self._run_bucket(take)
-            if not q:
+                due.append(q[:self.max_batch])
+                q = q[self.max_batch:]
+            if q:
+                self._buckets[bkey] = q
+            else:
                 del self._buckets[bkey]
-        while self._solo:
-            self._run_solo(self._solo.pop(0))
+        if due or self._solo:
+            with _obs_trace.ACTIVE.span("serve.poll", cat="serve",
+                                        launches=len(due) + len(self._solo),
+                                        queue_depth=depth):
+                for take in due:
+                    self._run_bucket(take)
+                while self._solo:
+                    self._run_solo(self._solo.pop(0))
         self._note_queue()
         return dict(self._results)
 
@@ -650,7 +661,8 @@ class ClusterServeEngine:
         with _obs_trace.ACTIVE.span("serve.bucket_solve", cat="serve",
                                     bucket=str(spec.key), mode=spec.mode,
                                     batch=len(pends), n=spec.n,
-                                    nnz=spec.nnz, k=spec.k) as sp:
+                                    nnz=spec.nnz, k=spec.k,
+                                    req_ids=[p.req_id for p in pends]) as sp:
             U, fvals = solver(jnp.asarray(_fill(batch.rows)),
                               jnp.asarray(_fill(batch.cols)),
                               jnp.asarray(_fill(batch.vals)),
@@ -668,6 +680,7 @@ class ClusterServeEngine:
         self.metrics.histogram("serve_batch_occupancy",
                                buckets=(1, 2, 4, 8, 16, 32)
                                ).observe(len(pends))
+        launched = time.monotonic()
         try:
             U, trace_new, solve_s = self._solve_bucket(pends, spec)
         except (KeyboardInterrupt, SystemExit):
@@ -705,7 +718,8 @@ class ClusterServeEngine:
                                  "solve (request-local divergence)",
                            kind="nonfinite_result", lane="bucket")
                 continue
-            self._finish(pend, Ub, lane="bucket", batch_size=len(pends),
+            self._finish(pend, Ub, lane="bucket", lane_index=b,
+                         batch_size=len(pends), launched=launched,
                          solve_s=solve_s, trace_new=trace_new,
                          p_final=p_final, hierarchy=None)
 
@@ -778,7 +792,8 @@ class ClusterServeEngine:
                 solve_s = time.monotonic() - t0
                 self.stats.solve_s += solve_s
                 self._finish(pend, np.asarray(jnp.linalg.qr(U0)[0]),
-                             lane="solo", batch_size=1, solve_s=solve_s,
+                             lane="solo", lane_index=0, batch_size=1,
+                             launched=t0, solve_s=solve_s,
                              trace_new=False, p_final=2.0, hierarchy=None)
                 return
             else:
@@ -829,46 +844,51 @@ class ClusterServeEngine:
         sp.set(retries=retries)
         p_final = res.p_path[-1] if res.p_path else \
             float(registry.p_schedule(self.cfg)[-1])
-        self._finish(pend, np.asarray(res.U), lane="solo", batch_size=1,
-                     solve_s=solve_s, trace_new=False, p_final=p_final,
-                     hierarchy=hierarchy, precomputed=res, retries=retries)
+        self._finish(pend, np.asarray(res.U), lane="solo", lane_index=0,
+                     batch_size=1, launched=t0, solve_s=solve_s,
+                     trace_new=False, p_final=p_final, hierarchy=hierarchy,
+                     precomputed=res, retries=retries)
 
     def _finish(self, pend: _Pending, U: np.ndarray, *, lane: str,
-                batch_size: int, solve_s: float, trace_new: bool,
-                p_final: float, hierarchy, precomputed=None,
-                retries: int = 0) -> None:
+                lane_index: int, batch_size: int, launched: float,
+                solve_s: float, trace_new: bool, p_final: float, hierarchy,
+                precomputed=None, retries: int = 0) -> None:
         """Stage 3 + metrics on the caller's original graph, cache
-        store, stats."""
+        store, stats.  ``launched`` is when the solve that served this
+        request started; the ``serve.finish`` span and ``finish_s``
+        cover this request's stage 3 alone (its end follows host
+        reads of the labels and cuts, so it needs no fence)."""
         W, k = pend.W, pend.k
-        if precomputed is not None:
-            labels = np.asarray(precomputed.labels)
-            rcut, ncut = precomputed.rcut, precomputed.ncut
-        else:
-            _, k_final = _psc.stage_keys(self.cfg.seed)
-            labels = np.asarray(_psc.discretize(
-                jnp.asarray(U), k, k_final,
-                restarts=self.cfg.kmeans_restarts,
-                iters=self.cfg.kmeans_iters))
-            rcut = float(metrics.rcut(W, labels, k))
-            ncut = float(metrics.ncut(W, labels, k))
-        self.cache.store(CacheEntry(
-            U=np.asarray(U), labels=labels, p_final=p_final, rcut=rcut,
-            fingerprint=pend.fp, hierarchy=hierarchy))
-        done = time.monotonic()
+        t0 = time.monotonic()
+        with _obs_trace.ACTIVE.span("serve.finish", cat="serve",
+                                    req_id=pend.req_id, lane=lane,
+                                    lane_index=lane_index):
+            if precomputed is not None:
+                labels = np.asarray(precomputed.labels)
+                rcut, ncut = precomputed.rcut, precomputed.ncut
+            else:
+                _, k_final = _psc.stage_keys(self.cfg.seed)
+                labels = np.asarray(_psc.discretize(
+                    jnp.asarray(U), k, k_final,
+                    restarts=self.cfg.kmeans_restarts,
+                    iters=self.cfg.kmeans_iters))
+                rcut = float(metrics.rcut(W, labels, k))
+                ncut = float(metrics.ncut(W, labels, k))
+            self.cache.store(CacheEntry(
+                U=np.asarray(U), labels=labels, p_final=p_final, rcut=rcut,
+                fingerprint=pend.fp, hierarchy=hierarchy))
+        finish_s = time.monotonic() - t0
         st = ServeStats(
             req_id=pend.req_id, n=W.n_rows, nnz=W.nnz, k=k, lane=lane,
             mode="churn" if pend.churn else pend.mode,
             cache_tier=pend.cache_tier,
             bucket=pend.spec.key if pend.spec else None,
-            batch_size=batch_size, queue_s=done - pend.arrival - solve_s,
+            batch_size=batch_size, queue_s=launched - pend.arrival,
             solve_s=solve_s, trace_new=trace_new, p_final=p_final,
-            degrade=pend.degrade, retries=retries)
+            degrade=pend.degrade, retries=retries, finish_s=finish_s)
         self._results[pend.req_id] = ServeResult(
             req_id=pend.req_id, labels=labels, U=np.asarray(U), rcut=rcut,
             ncut=ncut, stats=st)
         self.stats.n_results += 1
         if pend.churn:
             self.stats.n_churn += 1
-        if self.stats.solve_s > 0:
-            self.stats.graphs_per_s = self.stats.n_results / \
-                self.stats.solve_s

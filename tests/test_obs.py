@@ -16,6 +16,7 @@ import time
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.core.psc import PSCConfig, p_spectral_cluster
@@ -97,6 +98,57 @@ def test_bounded_buffer_drops_past_capacity():
     assert len(tr.spans) == 4
     assert len(tr.events) == 4
     assert tr.dropped == 6 + 2
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """Replace ``jax.profiler.TraceAnnotation`` by a recorder of its
+    enters and exits, in order."""
+    log = []
+
+    class Note:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Note)
+    return log
+
+
+def _nested_and_misnested(tr):
+    with use(tr):
+        with tr.span("root"):
+            with tr.span("a"):
+                pass
+            outer, inner = tr.span("b"), tr.span("c")
+            outer.__enter__()
+            inner.__enter__()
+            outer.__exit__(None, None, None)    # b exits while c is open
+            inner.__exit__(None, None, None)    # c was unwound with b
+
+
+def test_annotate_mirrors_each_span_as_a_profiler_annotation(annotations):
+    tr = Tracer(TraceConfig(fence=False, annotate=True))
+    _nested_and_misnested(tr)
+    assert annotations == [("enter", "root"), ("enter", "a"), ("exit", "a"),
+                           ("enter", "b"), ("enter", "c"), ("exit", "c"),
+                           ("exit", "b"), ("exit", "root")]
+    assert sorted(s.name for s in tr.spans) == ["a", "b", "c", "root"]
+    assert tr._stack == [] and tr._annotations == []
+
+
+def test_default_config_opens_no_profiler_annotation(annotations):
+    tr = Tracer(TraceConfig(fence=False))
+    _nested_and_misnested(tr)
+    assert annotations == []
+    assert sorted(s.name for s in tr.spans) == ["a", "b", "c", "root"]
 
 
 def test_null_tracer_is_the_default_and_free():

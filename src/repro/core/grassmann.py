@@ -118,21 +118,25 @@ def _tcg(U, grad, hvp, radius, tcg_iters: int, kappa=0.1, theta=1.0):
     return out.eta, out.n_hvp
 
 
-def rtr_minimize(f: Callable, egrad: Callable, ehvp: Callable, U0: jnp.ndarray,
+def rtr_minimize(f: Callable, egrad: Callable, ehvp_at: Callable,
+                 U0: jnp.ndarray,
                  max_iters: int = 50, tcg_iters: int = 25,
                  grad_tol: float = 1e-6, radius0: float = 0.5,
                  radius_max: float = 4.0) -> RTRResult:
     """Trust-region Newton on Gr(k,n).
 
-    f(U) -> scalar; egrad(U) -> (n,k); ehvp(U, eta) -> (n,k) Euclidean HVP.
+    f(U) -> scalar; egrad(U) -> (n,k); ehvp_at(U) -> (eta -> (n,k)), the
+    Euclidean HVP at U.  ehvp_at is called once per Newton step, outside
+    the tCG loop, so what the Hessian apply builds from U alone can be
+    built once per step (``plap.hvp_at``).
     """
 
     def rgrad(U):
         return proj(U, egrad(U))
 
-    def rhess(U, g_e, eta):
+    def rhess(U, g_e, ehvp_U, eta):
         # Grassmann Hessian: proj( ehvp - eta (U^T egrad) )
-        return proj(U, ehvp(U, eta) - eta @ (U.T @ g_e))
+        return proj(U, ehvp_U(eta) - eta @ (U.T @ g_e))
 
     def cond(s: RTRState):
         return jnp.logical_and(s.it < max_iters, s.gradnorm > grad_tol)
@@ -140,7 +144,8 @@ def rtr_minimize(f: Callable, egrad: Callable, ehvp: Callable, U0: jnp.ndarray,
     def body(s: RTRState):
         g_e = egrad(s.U)
         g = proj(s.U, g_e)
-        hvp = lambda eta: rhess(s.U, g_e, eta)
+        ehvp_U = ehvp_at(s.U)
+        hvp = lambda eta: rhess(s.U, g_e, ehvp_U, eta)
         eta, used = _tcg(s.U, g, hvp, s.radius, tcg_iters)
         U_try = retract_qr(s.U, eta)
         f_try = f(U_try)

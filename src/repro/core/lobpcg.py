@@ -16,7 +16,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.grblas.containers import SparseMatrix
+from repro.grblas.containers import DENSE_MAX_N, SparseMatrix
 from repro.grblas import api
 from repro.grblas.api import Descriptor
 
@@ -166,7 +166,7 @@ def smallest_eigvecs(W: SparseMatrix, k: int, normalized: bool = False,
     SCF driver restarts each reweighted eigensolve from the previous
     sweep's eigenvectors this way.  The dense exact path ignores it."""
     n = W.n_rows
-    if n <= 1024:  # dense exact path for tiny graphs
+    if n <= DENSE_MAX_N:  # dense exact path for tiny graphs
         L = jnp.diag(W.row_sums()) - W.to_dense()
         if normalized:
             d = jnp.maximum(W.row_sums(), 1e-12)

@@ -21,9 +21,11 @@ Every SpMM-shaped reduction routes through the unified GraphBLAS API
 (grblas.api.mxm) under a Descriptor — backend="auto" serves the Newton
 hot loop from the fused Pallas kernels when the BSR layout is built (on
 TPU), the SELL-C-σ sliced gather path when that layout is built (the
-skewed-degree scaling regime, DESIGN.md §5), and the COO/ELL gather
-paths otherwise; there are no raw jax.ops.segment_sum calls left in the
-hot path.
+skewed-degree scaling regime, DESIGN.md §5), the dense backend when a
+caller built the dense (n, n) layout (the serve bucket lane: every edge
+sum is then a (k, n, n) elementwise fold), and the COO/ELL gather paths
+otherwise; there are no raw jax.ops.segment_sum calls left in the hot
+path.
 
 Two HVP implementations:
   * hess_eta_graphblas  — Algorithm-1-faithful: materialize D[l] and the
@@ -38,6 +40,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.grblas.containers import SparseMatrix
@@ -60,8 +63,27 @@ class PLapParts(NamedTuple):
 
 
 def _edge_diffs(W: SparseMatrix, U: jnp.ndarray) -> jnp.ndarray:
-    """d_e = u_i - u_j per nnz edge (directed; W stores both (i,j),(j,i))."""
+    """d_e = u_i - u_j per nnz edge (directed; W stores both (i,j),(j,i)),
+    (nnz, k).  On the dense layout every (i, j) pair, (k, n, n): an
+    absent edge carries w 0 in ``_edge_weights``."""
+    if W.dense is not None:
+        Ut = U.T
+        return Ut[:, :, None] - Ut[:, None, :]
     return U[W.rows] - U[W.cols]
+
+
+def _edge_weights(W: SparseMatrix) -> jnp.ndarray:
+    """w_e laid out to broadcast against ``_edge_diffs``."""
+    if W.dense is not None:
+        return W.dense[None]
+    return W.vals[:, None]
+
+
+def _edge_total(W: SparseMatrix, x: jnp.ndarray) -> jnp.ndarray:
+    """Per-column sum of an ``_edge_diffs``-shaped array, (k,)."""
+    if W.dense is not None:
+        return jnp.sum(x, axis=(1, 2))
+    return jnp.sum(x, axis=0)
 
 
 def parts(W: SparseMatrix, U: jnp.ndarray, p: float, eps: float,
@@ -69,8 +91,8 @@ def parts(W: SparseMatrix, U: jnp.ndarray, p: float, eps: float,
     """All shared quantities for value/grad: one edge pass for the scalar
     energies + one edge-semiring SpMM for Delta_p u (the kernel-served op)."""
     d = _edge_diffs(W, U)                                    # (nnz, k)
-    w = W.vals[:, None]
-    A = 0.5 * jnp.sum(w * PHI.p_power(d, p, eps), axis=0)    # (k,)
+    w = _edge_weights(W)
+    A = 0.5 * _edge_total(W, w * PHI.p_power(d, p, eps))     # (k,)
     B = jnp.sum(PHI.p_power(U, p, eps), axis=0)              # (k,)
     dpu = api.mxm(W, U, plap_edge_semiring(p, eps), desc=desc or _AUTO)
     return PLapParts(A=A, B=B, F=A / B, dpu=dpu, phi_u=PHI.phi(U, p, eps))
@@ -99,9 +121,10 @@ def value_and_grad(W: SparseMatrix, U: jnp.ndarray, p: float, eps: float = 1e-9,
 # ---------------------------------------------------------------- HVP paths
 
 def hessian_weights(W: SparseMatrix, U: jnp.ndarray, p: float, eps: float):
-    """w-hat_e = w_e phi'(u_i - u_j) per edge and column. (nnz,k)."""
+    """w-hat_e = w_e phi'(u_i - u_j) per edge and column. (nnz,k); on the
+    dense layout (k, n, n) dense multivalues (``W.with_vals``)."""
     d = _edge_diffs(W, U)
-    return W.vals[:, None] * PHI.phi_prime(d, p, eps)
+    return _edge_weights(W) * PHI.phi_prime(d, p, eps)
 
 
 def build_alg1_operands(W: SparseMatrix, U: jnp.ndarray, p: float, eps: float,
@@ -109,7 +132,8 @@ def build_alg1_operands(W: SparseMatrix, U: jnp.ndarray, p: float, eps: float,
     """The paper's Algorithm-1 inputs: per column l,
        D[l] = diag(Hess A^l) / p   (vector)  and
        H[l] = off-diagonal W-hat^l (multivalues on W's pattern).
-    Returned stacked over columns: D (n,k), What_vals (nnz,k).
+    Returned stacked over columns: D (n,k), What_vals (nnz,k) — or
+    (k,n,n) on the dense layout.
     D is the W-hat row sums — mxv with the ones multivector."""
     what = hessian_weights(W, U, p, eps)                     # (nnz,k)
     Wh = W.with_vals(what)
@@ -129,7 +153,6 @@ def _multival_desc(Wh: SparseMatrix, U, desc: Optional[Descriptor]):
 
 def hess_eta_graphblas(W: SparseMatrix, U: jnp.ndarray, eta: jnp.ndarray,
                        p: float, eps: float = 1e-9,
-                       operands=None,
                        desc: Optional[Descriptor] = None) -> jnp.ndarray:
     """Algorithm-1-faithful HVP (materialized W-hat), full quotient rule.
 
@@ -140,23 +163,13 @@ def hess_eta_graphblas(W: SparseMatrix, U: jnp.ndarray, eta: jnp.ndarray,
     then the rank-one quotient corrections (vector dots / axpys).
     The materialized multivalues run the COO backend — or the SELL-C-σ
     layout when built: with_vals re-scatters the packed slice values
-    on-device, so Alg-1's W-hat SpMM stays on the sliced layout too.
+    on-device, so Alg-1's W-hat SpMM stays on the sliced layout too —
+    or the dense backend on the dense layout, as (k, n, n) multivalues.
     ``desc`` steers ``parts`` and, when its backend can execute
-    multivalues (coo / sellcs), the W-hat SpMMs as well; hot-loop-only
-    backends (edge_pallas) degrade those two ops to "auto".
+    multivalues (coo / sellcs / dense), the W-hat SpMMs as well;
+    hot-loop-only backends (edge_pallas) degrade those two ops to "auto".
     """
-    pr = parts(W, U, p, eps, desc)
-    if operands is None:
-        operands = build_alg1_operands(W, U, p, eps, desc)
-    D, what_vals = operands
-
-    # lines 6-9 of Algorithm 1, k columns fused through one SpMM:
-    Wh = W.with_vals(what_vals)
-    v = api.mxm(Wh, eta, reals_ring, desc=_multival_desc(Wh, eta, desc))
-    w = grb.e_wise_apply(eta, D, jnp.multiply)
-    hA_eta = p * grb.e_wise_apply(w, v, jnp.subtract)        # Hess A @ eta
-
-    return _quotient_correct(pr, U, eta, hA_eta, p, eps)
+    return _hvp_operator(W, U, p, eps, "graphblas", desc)(eta)
 
 
 def hess_eta_matrix_free(W: SparseMatrix, U: jnp.ndarray, eta: jnp.ndarray,
@@ -167,10 +180,46 @@ def hess_eta_matrix_free(W: SparseMatrix, U: jnp.ndarray, eta: jnp.ndarray,
         = p * sum_j w-hat_ij (eta_i - eta_j)
     with w-hat computed per edge inside the ring (Pallas kernel when the
     BSR layout is built on TPU; COO segment path otherwise)."""
+    return _hvp_operator(W, U, p, eps, "matrix_free", desc)(eta)
+
+
+def hvp_at(W: SparseMatrix, U: jnp.ndarray, p: float, eps: float = 1e-9,
+           mode: str = "graphblas",
+           desc: Optional[Descriptor] = None):
+    """eta -> Hess F_p(U) @ eta at a fixed U: ``hess_eta_graphblas``
+    (``mode`` "graphblas") or ``hess_eta_matrix_free`` ("matrix_free")
+    for a caller that applies the Hessian many times at one point (the
+    tCG loop of one Newton step).  What depends on U alone — ``parts``
+    and, for "graphblas", Algorithm 1's D and W-hat — is built once,
+    here, behind an optimization barrier: without it XLA sinks the W-hat
+    elementwise chain back into the product's fusion inside the tCG
+    loop, and recomputes it at every tCG step."""
+    return _hvp_operator(W, U, p, eps, mode, desc,
+                         keep=jax.lax.optimization_barrier)
+
+
+def _hvp_operator(W, U, p, eps, mode, desc, keep=lambda ops: ops):
     pr = parts(W, U, p, eps, desc)
-    hA_eta = p * api.mxm(W, (U, eta), plap_hvp_edge_semiring(p, eps),
-                         desc=desc or _AUTO)
-    return _quotient_correct(pr, U, eta, hA_eta, p, eps)
+    if mode == "graphblas":
+        pr, (D, what_vals) = keep(
+            (pr, build_alg1_operands(W, U, p, eps, desc)))
+        Wh = W.with_vals(what_vals)
+
+        def apply(eta):
+            # lines 6-9 of Algorithm 1, k columns fused through one SpMM:
+            v = api.mxm(Wh, eta, reals_ring,
+                        desc=_multival_desc(Wh, eta, desc))
+            w = grb.e_wise_apply(eta, D, jnp.multiply)
+            hA_eta = p * grb.e_wise_apply(w, v, jnp.subtract)  # Hess A @ eta
+            return _quotient_correct(pr, U, eta, hA_eta, p, eps)
+    else:
+        pr = keep(pr)
+
+        def apply(eta):
+            hA_eta = p * api.mxm(W, (U, eta), plap_hvp_edge_semiring(p, eps),
+                                 desc=desc or _AUTO)
+            return _quotient_correct(pr, U, eta, hA_eta, p, eps)
+    return apply
 
 
 def _quotient_correct(pr: PLapParts, U, eta, hA_eta, p, eps):
@@ -200,6 +249,5 @@ def autodiff_value(W: SparseMatrix, p: float, eps: float):
 
 
 def autodiff_hvp(W: SparseMatrix, U, eta, p: float, eps: float = 1e-9):
-    import jax
     f = autodiff_value(W, p, eps)
     return jax.jvp(jax.grad(f), (U,), (eta,))[1]

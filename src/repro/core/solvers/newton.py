@@ -12,6 +12,8 @@ through ``registry.backend_bakes_ring_params``.
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 
@@ -59,7 +61,8 @@ def _jitted_minimize(cfg, p, W, U0):
             else:
                 h = lambda U, eta: plap.hess_eta_matrix_free(W, U, eta, p_run,
                                                              eps, desc=desc)
-            return rtr_minimize(f, g, h, U0, max_iters=newton_iters,
+            return rtr_minimize(f, g, lambda U: partial(h, U), U0,
+                                max_iters=newton_iters,
                                 tcg_iters=tcg_iters, grad_tol=grad_tol)
 
         if static_p is None:

@@ -11,7 +11,7 @@ The ``Descriptor`` replaces the old scatter of ``use_ell`` /
 kernels.bsr_spmm.bsr_spmm, kernels.plap_edge.plap_apply, dist.dist_mxm):
 
     backend    "auto" | "coo" | "ell" | "sellcs" | "bsr_pallas" |
-               "edge_pallas" | "dist" | "dist_sellcs" | "spgemm"
+               "edge_pallas" | "dist" | "dist_sellcs" | "spgemm" | "dense"
     transpose  operate on A^T (COO index-role swap; vxm flips this)
     interpret  run Pallas kernels in interpreter mode (CPU numerics pin)
     mesh/axis  device mesh + axis name for the "dist"/"dist_sellcs"

@@ -15,6 +15,8 @@ Registered backends (priority: lower = preferred under "auto"):
   dist         ELL / row-part  reals, edge (reals base)      0    0  (needs desc.mesh)
   dist_sellcs  row-part + per- same gates as dist, square    1    1  (needs desc.mesh)
                shard SELL-C-σ  only
+  dense        dense tile      reals (incl. (k, n, n) multi-  5    5
+                               vals) + plap edge kinds
   edge_pallas  BSR tiles       plap_apply / plap_hvp kinds  61   10
   bsr_pallas   BSR tiles       reals                        60   11
   sellcs       SELL-C-σ        padded-reducer rings (incl.  19   12
@@ -44,6 +46,11 @@ but on CPU the gather/segment formulations win.  The SELL-C-σ kernels
 are the exception: the TPU compiler refuses them, so "sellcs" runs its
 jnp/XLA path on the chip (``sellcs_uses_pallas``).  ``dist`` outranks
 everything once a mesh is supplied — the caller asked for sharding.
+
+"dense" runs only on a matrix whose dense layout is built
+(``SparseMatrix.with_dense``; no constructor builds it), so "auto" picks
+it only where a caller asked for the tile: the serve bucket lane, for
+buckets of at most DENSE_MAX_N vertices.
 
 New hardware or layouts are one ``register_backend`` call, not a fifth
 parallel entry point (DESIGN.md §3).
@@ -187,7 +194,11 @@ def _coo_supports(A, X, ring, desc):
 
 def _vals_match(A, X) -> bool:
     """(nnz, k) multivalues (with_vals) only broadcast against an (n, k)
-    multivector — reject 1-D inputs at dispatch time, not mid-broadcast."""
+    multivector — reject 1-D inputs at dispatch time, not mid-broadcast.
+    A dense-multivalue matrix (with_vals on the dense layout) has no COO
+    values at all."""
+    if A.vals is None:
+        return False
     return A.vals.ndim == 1 or getattr(X, "ndim", 0) == 2
 
 
@@ -382,6 +393,60 @@ def _sellcs_execute(A, X, ring, desc):
     The σ permutation is applied to the multivector on the way in and
     inverted on the way out — callers never observe it."""
     return sellcs_run(A, X, ring, interpret=desc.interpret)
+
+
+# ------------------------------------------------------------- dense backend
+
+def _dense_supports(A, X, ring, desc):
+    """The dense tile's absent entries are exact zeros, so, like a pad
+    slot, each contributes mul(0, ...): sound for the reals ring and for
+    the plap edge kinds, whose multiply annihilates on w = 0.  (k, n, n)
+    multivalues serve the reals ring against an (n, k) multivector."""
+    if not (isinstance(A, SparseMatrix) and A.dense is not None
+            and not _is_sparse(X) and not desc.transpose):
+        return False
+    multi = A.dense.ndim == 3
+    if isinstance(ring, PairEdgeSemiring):
+        return (ring.kind == "plap_hvp" and not multi and _square(A)
+                and _is_pair(X) and len(X) == 2
+                and getattr(X[0], "ndim", 0) == 2
+                and X[0].shape == X[1].shape)
+    if isinstance(ring, EdgeSemiring):
+        return (ring.kind == "plap_apply" and not multi and _square(A)
+                and getattr(X, "ndim", 0) == 2)
+    if not (isinstance(ring, Semiring) and ring.name == "reals_+x"
+            and not _is_pair(X)):
+        return False
+    if multi:
+        return (getattr(X, "ndim", 0) == 2
+                and X.shape[1] == A.dense.shape[0])
+    return getattr(X, "ndim", 0) in (1, 2)
+
+
+@register_backend("dense", cpu_priority=5, tpu_priority=5,
+                  supports=_dense_supports)
+def _dense_execute(A, X, ring, desc):
+    """Every (i, j) pair of the tile at once, with no gather or scatter:
+    column l of the product is the row sum of a (n, n) slab, laid out
+    (k, n, n) so the vertex axis, not k, is minor (a minor k of 4 would
+    pad to the TPU's 128 lanes).  Products are elementwise multiplies
+    summed in the operands' precision, never a default-precision (one
+    bfloat16 pass) MXU matmul; the fold is the reals ring's registered
+    dense reducer."""
+    fold = fast_paths(getattr(ring, "base", ring)).dense
+    M = A.dense
+    if isinstance(ring, PairEdgeSemiring):
+        Ut, Et = X[0].T, X[1].T
+        contrib = ring.edge_mul(M, Ut[:, None, :], Ut[:, :, None],
+                                Et[:, None, :], Et[:, :, None])
+    elif isinstance(ring, EdgeSemiring):
+        Xt = X.T
+        contrib = ring.edge_mul(M, Xt[:, None, :], Xt[:, :, None])
+    elif X.ndim == 1:
+        return fold(M * X[None, :], -1)
+    else:
+        contrib = (M if M.ndim == 3 else M[None]) * X.T[:, None, :]
+    return fold(contrib, -1).T
 
 
 # -------------------------------------------------------- bsr_pallas backend

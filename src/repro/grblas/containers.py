@@ -1,6 +1,6 @@
 """Algebraic containers: sparse matrices in JAX-friendly layouts.
 
-A ``SparseMatrix`` carries up to four layouts of the same matrix:
+A ``SparseMatrix`` carries up to six layouts of the same matrix:
 
   * COO    (rows, cols, vals)           — construction + segment-sum SpMV
   * CSR    (indptr, cols, vals)         — host-side utilities / export
@@ -15,6 +15,10 @@ A ``SparseMatrix`` carries up to four layouts of the same matrix:
                                           ELL on skewed-degree graphs.
   * BSR    (block ptrs/idx, dense tiles)— 128x128 dense tiles for the MXU
                                           Pallas kernel (kernels/bsr_spmm)
+  * dense  (n, n) tile                  — the whole matrix as one tile, for
+                                          graphs of at most DENSE_MAX_N
+                                          vertices (the "dense" backend);
+                                          built on device, traceable
 
 All device arrays are static-shaped so every op jits.  Construction is
 host-side (numpy/scipy); the resulting container is a pytree of jnp
@@ -50,6 +54,12 @@ import jax.numpy as jnp
 # more than this multiple of nnz, from_coo builds the SELL-C-σ layout as
 # well and backend auto-selection prefers it over ELL (grblas.backends).
 SELLCS_AUTO_THRESHOLD = 4.0
+
+# Largest vertex count handled as one dense (n, n) tile: the exact-eigh
+# path of lobpcg.smallest_eigvecs and the serve bucket lane's dense
+# layout.  One step up (n 2048), a bucket launch's (batch 8, k 4, n, n)
+# float32 pairwise tensors would pass 0.5 GB each.
+DENSE_MAX_N = 1024
 
 
 class GraphFingerprint(NamedTuple):
@@ -90,7 +100,8 @@ class SparseMatrix:
     n_rows: int
     n_cols: int
     nnz: int
-    # COO (always present, sorted by row then col)
+    # COO (always present, sorted by row then col; vals is None only on
+    # dense multivalues, see with_vals)
     rows: jnp.ndarray  # (nnz,) int32
     cols: jnp.ndarray  # (nnz,) int32
     vals: jnp.ndarray  # (nnz,) dtype
@@ -117,13 +128,17 @@ class SparseMatrix:
     sell_cols: Optional[Tuple[jnp.ndarray, ...]] = None  # per run (rows_r, w_r) int32, permuted space
     sell_vals: Optional[Tuple[jnp.ndarray, ...]] = None  # per run (rows_r, w_r[, k]) dtype
     sell_scatter: Optional[Tuple[jnp.ndarray, ...]] = None  # per run (rows_r, w_r) int32 -> nnz idx (pad=nnz)
+    # dense (optional): (n_rows, n_cols), or (k, n_rows, n_cols) dense
+    # multivalues (with_vals); absent entries are exact zeros
+    dense: Optional[jnp.ndarray] = None
 
     # ---- pytree protocol ----
     def tree_flatten(self):
         children = (self.rows, self.cols, self.vals, self.ell_cols,
                     self.ell_vals, self.bsr_indices, self.bsr_blocks,
                     self.bsr_row_ids, self.sell_perm, self.sell_inv,
-                    self.sell_cols, self.sell_vals, self.sell_scatter)
+                    self.sell_cols, self.sell_vals, self.sell_scatter,
+                    self.dense)
         aux = (self.n_rows, self.n_cols, self.nnz, self.block_size,
                None if self.bsr_indptr is None else tuple(self.bsr_indptr.tolist()),
                self.sell_c, self.sell_sigma, self.sell_w_align,
@@ -134,7 +149,7 @@ class SparseMatrix:
     def tree_unflatten(cls, aux, children):
         (rows, cols, vals, ell_cols, ell_vals, bsr_indices, bsr_blocks,
          bsr_row_ids, sell_perm, sell_inv, sell_cols, sell_vals,
-         sell_scatter) = children
+         sell_scatter, dense) = children
         (n_rows, n_cols, nnz, block_size, indptr,
          sell_c, sell_sigma, sell_w_align, sell_n_pad, sell_row0) = aux
         return cls(n_rows=n_rows, n_cols=n_cols, nnz=nnz, rows=rows, cols=cols,
@@ -148,7 +163,7 @@ class SparseMatrix:
                    sell_n_pad=sell_n_pad, sell_row0=sell_row0,
                    sell_perm=sell_perm, sell_inv=sell_inv,
                    sell_cols=sell_cols, sell_vals=sell_vals,
-                   sell_scatter=sell_scatter)
+                   sell_scatter=sell_scatter, dense=dense)
 
     # ---- constructors ----
     @staticmethod
@@ -352,15 +367,29 @@ class SparseMatrix:
         self.sell_scatter = tuple(run_scat)
 
     # ---- conveniences ----
+    def with_dense(self) -> "SparseMatrix":
+        """This matrix with its dense (n, n) layout built on device from
+        the COO triple (one scatter-add, traceable: the serve bucket lane
+        builds it once per launch inside its jitted solve)."""
+        return dataclasses.replace(self, dense=self.to_dense())
+
     def with_vals(self, vals: jnp.ndarray) -> "SparseMatrix":
         """Same sparsity pattern, new values — GraphBLAS' "new matrix on
         the old structure" (Algorithm 1 builds W-hat this way each Newton
         step).  ``vals`` may be (nnz,) or (nnz, k) *multivalues* (one
         value per stored entry per output column; backends broadcast them
-        against an (n, k) multivector).  Derived ELL/BSR layouts are
-        dropped (they would be stale), but the SELL-C-σ layout survives:
-        its scatter map rebuilds the packed values on-device, so the
-        materialized Alg-1 W-hat path runs on the sliced layout too."""
+        against an (n, k) multivector).  Derived ELL/BSR/dense layouts
+        are dropped (they would be stale), but the SELL-C-σ layout
+        survives: its scatter map rebuilds the packed values on-device,
+        so the materialized Alg-1 W-hat path runs on the sliced layout
+        too.  On a matrix with the dense layout, (k, n, n) ``vals`` are
+        dense multivalues (tile l serves output column l): they become
+        the new matrix's dense layout, and its COO ``vals`` are None, so
+        only the "dense" backend can execute it."""
+        if self.dense is not None and vals.ndim == 3:
+            return SparseMatrix(n_rows=self.n_rows, n_cols=self.n_cols,
+                                nnz=self.nnz, rows=self.rows,
+                                cols=self.cols, vals=None, dense=vals)
         m = SparseMatrix(n_rows=self.n_rows, n_cols=self.n_cols,
                          nnz=self.nnz, rows=self.rows, cols=self.cols,
                          vals=vals)
@@ -453,6 +482,15 @@ class SparseMatrix:
                                 np.zeros(pad, np.asarray(vals).dtype)]))
 
     def to_dense(self) -> jnp.ndarray:
+        """The (n, n) matrix: the dense layout when it is built, else
+        scattered from the COO triple.  Dense multivalues (with_vals of a
+        (k, n, n) tile) are k matrices and have no COO values: refused."""
+        if self.dense is not None:
+            if self.dense.ndim != 2:
+                raise ValueError(
+                    f"to_dense of (k, n, n) dense multivalues "
+                    f"{self.dense.shape}: k matrices, not one")
+            return self.dense
         d = jnp.zeros((self.n_rows, self.n_cols), self.vals.dtype)
         return d.at[self.rows, self.cols].add(self.vals)
 

@@ -9,10 +9,13 @@ the clustering analogue for a stream of graph requests:
     (n, nnz, k) bucket lattice (``serve.bucketing``) and the whole
     SCF/Newton p-continuation runs ``jax.vmap``-ed across a bucket, so
     each bucket compiles exactly one trace no matter how many requests
-    it serves.  The per-bucket jitted solve is memoized through the
-    solver registry's trace scaffolding (``registry.memoized`` /
-    ``mark_trace``), so retraces are observable the same way the Newton
-    driver's are.
+    it serves.  A Newton bucket of at most ``DENSE_MAX_N`` vertices
+    runs its p-Laplacian on each lane's dense (n, n) adjacency (the
+    "dense" backend: no gather or scatter in the solver's loops);
+    larger buckets run the COO segment path.  The per-bucket jitted
+    solve is memoized through the solver registry's trace scaffolding
+    (``registry.memoized`` / ``mark_trace``), so retraces are observable
+    the same way the Newton driver's are.
   * **warm-start cache** — an LRU on graph fingerprints
     (``serve.warm_cache``).  A hit skips the p=2 eigensolve and the
     continuation descent entirely: the cached embedding re-enters the
@@ -56,7 +59,8 @@ from repro.core.solvers import registry
 from repro.core.solvers.guard import SolverDivergence
 from repro.grblas.api import Descriptor
 from repro.grblas.backends import BackendUnavailableError
-from repro.grblas.containers import GraphFingerprint, SparseMatrix
+from repro.grblas.containers import (DENSE_MAX_N, GraphFingerprint,
+                                     SparseMatrix)
 from repro.obs import metrics as _obs_metrics
 from repro.obs import trace as _obs_trace
 from repro.serve.bucketing import (BucketBatch, BucketSpec, assemble_batch,
@@ -72,6 +76,7 @@ from repro.serve.warm_cache import CacheEntry, WarmCache
 _PAD_SHIFT = 1.0e6
 
 _COO = Descriptor(backend="coo")
+_DENSE = Descriptor(backend="dense")
 
 # Fault-injection seams (repro.testing.faultinject, DESIGN.md §9): when
 # set, called right before a bucket batch solve / a churn re-solve.
@@ -169,26 +174,36 @@ def _batched_init(W: SparseMatrix, mask: jnp.ndarray, k: int, cfg):
     return jnp.linalg.qr(U)[0]
 
 
+def _bucket_layout(spec: BucketSpec, cfg) -> str:
+    """The layout a bucket's Newton solve runs on: "dense" up to
+    ``DENSE_MAX_N`` vertices (the n where stage 1 goes dense too), "coo"
+    above it and for the scf step."""
+    if cfg.solver == "newton" and spec.n <= DENSE_MAX_N:
+        return "dense"
+    return "coo"
+
+
 def _make_level_step(cfg):
     """One continuation level of the batched solve: (W, mask, U, p) ->
     (U', fval), traceable end to end (vmap/scan-safe).
 
-    newton: ``rtr_minimize`` verbatim — its lax.while_loop batches with
+    newton: ``rtr_minimize`` — its lax.while_loop batches with
     per-element semantics, so each graph in the bucket keeps its own
-    trust-region trajectory.  scf: fixed-sweep IRLS with a per-element
-    convergence freeze (a converged element stops updating, matching the
-    host driver's early exit) and the dense eigensolve of the flat
-    ≤1024-vertex path."""
+    trust-region trajectory; the Hessian's U-only operands are built
+    once per Newton step (``plap.hvp_at``), on the dense backend when W
+    carries its dense layout (``_bucket_layout``), else on COO.
+    scf: fixed-sweep IRLS with a per-element convergence freeze (a
+    converged element stops updating, matching the host driver's early
+    exit) and the dense eigensolve of the flat ≤1024-vertex path."""
     eps = cfg.eps
     if cfg.solver == "newton":
-        hvp = (plap.hess_eta_graphblas if cfg.hvp_mode == "graphblas"
-               else plap.hess_eta_matrix_free)
-
         def step(W, mask, U, p):
-            f = lambda V: plap.value(W, V, p, eps, desc=_COO)
-            g = lambda V: plap.euc_grad(W, V, p, eps, desc=_COO)
-            h = lambda V, eta: hvp(W, V, eta, p, eps, desc=_COO)
-            res = rtr_minimize(f, g, h, U, max_iters=cfg.newton_iters,
+            desc = _COO if W.dense is None else _DENSE
+            f = lambda V: plap.value(W, V, p, eps, desc=desc)
+            g = lambda V: plap.euc_grad(W, V, p, eps, desc=desc)
+            h_at = lambda V: plap.hvp_at(W, V, p, eps, cfg.hvp_mode,
+                                         desc=desc)
+            res = rtr_minimize(f, g, h_at, U, max_iters=cfg.newton_iters,
                                tcg_iters=cfg.tcg_iters,
                                grad_tol=cfg.grad_tol)
             return res.U, res.fval
@@ -237,6 +252,8 @@ def _bucket_solver(spec: BucketSpec, cfg):
     Cold: dense p=2 init + lax.scan over the full continuation schedule
     (p traced per scan step, static length).  Warm: scan over the last
     ``cfg.warm_p_steps`` schedule values from the supplied embeddings.
+    On the "dense" layout (``_bucket_layout``) each lane's adjacency
+    tile is built once, before both.
     Exactly one trace per (spec, solver signature) — ``mark_trace``
     lands the key in ``registry.SOLVER_TRACES`` so tests and the bench
     can assert trace reuse across a mixed request stream."""
@@ -248,12 +265,15 @@ def _bucket_solver(spec: BucketSpec, cfg):
         else:
             tail = registry.p_schedule(cfg)[-max(int(cfg.warm_p_steps), 1):]
             ps = jnp.asarray(tail, jnp.float32)
+        layout = _bucket_layout(spec, cfg)
         step = _make_level_step(cfg)
         n_b, nnz_b, k = spec.n, spec.nnz, spec.k
 
         def one(rows, cols, vals, mask, U0):
             W = SparseMatrix(n_rows=n_b, n_cols=n_b, nnz=nnz_b,
                              rows=rows, cols=cols, vals=vals)
+            if layout == "dense":
+                W = W.with_dense()
             if spec.mode == "cold":
                 U = _batched_init(W, mask, k, cfg)
             else:
@@ -390,7 +410,7 @@ class ClusterServeEngine:
 
     def __init__(self, cfg: Optional[PSCConfig] = None, *,
                  cache_capacity: int = 64, max_batch: int = 8,
-                 max_wait_s: float = 0.05, max_bucket_n: int = 1024,
+                 max_wait_s: float = 0.05, max_bucket_n: int = DENSE_MAX_N,
                  min_bucket_n: int = 64, min_bucket_nnz: int = 128,
                  ml=None, weight_quant: float = 1e-6,
                  deadline_s: Optional[float] = None,
@@ -658,10 +678,13 @@ class ClusterServeEngine:
             return a if fill <= 0 else \
                 np.concatenate([a, np.repeat(a[-1:], fill, axis=0)])
 
+        layout = _bucket_layout(spec, self.cfg)
+        self.metrics.counter("serve_bucket_launches_total",
+                             layout=layout).inc()
         with _obs_trace.ACTIVE.span("serve.bucket_solve", cat="serve",
                                     bucket=str(spec.key), mode=spec.mode,
                                     batch=len(pends), n=spec.n,
-                                    nnz=spec.nnz, k=spec.k,
+                                    nnz=spec.nnz, k=spec.k, layout=layout,
                                     req_ids=[p.req_id for p in pends]) as sp:
             U, fvals = solver(jnp.asarray(_fill(batch.rows)),
                               jnp.asarray(_fill(batch.cols)),

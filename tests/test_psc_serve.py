@@ -124,6 +124,108 @@ def test_bucketed_solve_matches_flat(solver, flat_backend):
     assert res.stats.bucket == ("serve", "cold", 64, 512, 4)
 
 
+# ------------------------------------------------- the bucket lane's layout
+
+def _loop_ops(jaxpr, depth=0, out=None):
+    """(while-nesting depth, primitive name, input shapes) of every
+    equation, descending into every sub-jaxpr (jit, scan, while, cond)."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        out.append((depth, name,
+                    [tuple(getattr(v.aval, "shape", ())) for v in eqn.invars]))
+        inner = depth + (name == "while")
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                if hasattr(sub, "jaxpr") and hasattr(sub, "consts"):
+                    _loop_ops(sub.jaxpr, inner, out)
+    return out
+
+
+def _bucket_solver_ops(n, nnz, **kw):
+    """The cold bucket solver of an (n, nnz, k 4) bucket, traced at a
+    batch of 8 from abstract shapes (no compile, nothing runs)."""
+    from repro.serve import psc_engine
+
+    spec = BucketSpec(n=n, nnz=nnz, k=4, mode="cold")
+    solver, _ = psc_engine._bucket_solver(spec, _cfg(**kw))
+    idx = jax.ShapeDtypeStruct((8, nnz), np.int32)
+    jaxpr = jax.make_jaxpr(solver)(
+        idx, idx, jax.ShapeDtypeStruct((8, nnz), np.float32),
+        jax.ShapeDtypeStruct((8, n), np.float32),
+        jax.ShapeDtypeStruct((8, n, 4), np.float32))
+    return psc_engine._bucket_layout(spec, _cfg(**kw)), _loop_ops(jaxpr.jaxpr)
+
+
+def _vertex_loop_ops(ops, n, nnz):
+    """Names of the loop-body equations whose inputs carry the vertex
+    axis (n) or the edge axis (nnz): the p-Laplacian's work.  The
+    Newton step's own k x k algebra (the QR retraction's diagonal read)
+    is not the layout's and stays out."""
+    return {name for depth, name, shapes in ops if depth >= 1
+            and any(d in (n, nnz) for shape in shapes for d in shape)}
+
+
+@pytest.mark.parametrize("hvp_mode", ["graphblas", "matrix_free"])
+def test_dense_bucket_loops_have_no_gather_or_scatter(hvp_mode):
+    """At n_b 128 (the stream cell's bucket) the Newton and tCG loops
+    run on the dense tile: no gather and no scatter-add over the vertex
+    or edge axis in any while body (the tile's one scatter-add sits
+    outside, once per launch).  Under "graphblas" the tCG body holds no
+    transcendental over the (B, k, n, n) tile: W-hat is built once per
+    Newton step."""
+    layout, ops = _bucket_solver_ops(128, 2048, hvp_mode=hvp_mode)
+    assert layout == "dense"
+    assert "while" in {name for _, name, _ in ops}
+    assert not (_vertex_loop_ops(ops, 128, 2048)
+                & {"gather", "scatter-add", "scatter"})
+    assert [name for depth, name, _ in ops
+            if depth == 0 and name == "scatter-add"] == ["scatter-add"]
+    tile = (8, 4, 128, 128)
+    tcg_pows = [shapes for depth, name, shapes in ops
+                if depth == 2 and name == "pow" and tile in shapes]
+    assert (tcg_pows == []) == (hvp_mode == "graphblas")
+
+
+def test_bucket_above_cutoff_traces_coo():
+    """Above DENSE_MAX_N the bucket lane keeps the COO segment path."""
+    from repro.grblas.containers import DENSE_MAX_N
+
+    n, nnz = 2 * DENSE_MAX_N, 16384
+    layout, ops = _bucket_solver_ops(n, nnz)
+    assert layout == "coo"
+    assert {"gather", "scatter-add"} <= _vertex_loop_ops(ops, n, nnz)
+    assert not any(len(shapes) and len(shapes[0]) == 4
+                   for _, _, shapes in ops)
+
+
+def test_bucket_launch_counter_and_span_name_the_layout():
+    """One ``serve_bucket_launches_total{layout=...}`` count and one
+    ``serve.bucket_solve`` span ``layout`` per launch: dense for a Newton
+    bucket, coo for the scf step."""
+    from repro.obs import TraceConfig, Tracer, use
+
+    W, _ = ring_of_cliques(4, 10)
+    tr = Tracer(TraceConfig())
+    with use(tr):
+        eng = ClusterServeEngine(_cfg(newton_iters=4, tcg_iters=3),
+                                 max_batch=2)
+        done = eng.serve([_reweighted(W, 1.0 + 0.01 * i) for i in range(3)])
+        scf = ClusterServeEngine(_cfg(solver="scf", scf_sweeps=2))
+        scf.serve([W])
+    assert all(r.ok for r in done) and eng.stats.n_batches == 2
+    launches = eng.metrics.labeled_values("serve_bucket_launches_total",
+                                          "layout")
+    assert launches == {"dense": 2}
+    assert scf.metrics.labeled_values("serve_bucket_launches_total",
+                                      "layout") == {"coo": 1}
+    spans = [s.attrs["layout"] for s in tr.spans
+             if s.name == "serve.bucket_solve"]
+    assert spans == ["dense", "dense", "coo"]
+    assert 'serve_bucket_launches_total{layout="dense"} 2' in \
+        eng.exposition()
+
+
 def test_solo_lane_matches_flat_exactly():
     """Below-threshold bucket cap forces the solo lane, which IS the
     flat pipeline — bit-identical result."""

@@ -164,3 +164,70 @@ def test_edge_pallas_kernels_compile(one_chip, kind):
         args = (blocks, idx, idx, X, X)
     compiled = _compile(fn, *args)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _innermost_loop_text(hlo: str) -> str:
+    """The HLO text of the innermost ``while`` body (the one that holds
+    no other while), with every computation it calls."""
+    import re
+
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+
+    def closure(name, seen):
+        for callee in re.findall(r"(?:calls|to_apply|body|condition)=%?"
+                                 r"([\w.\-]+)", "\n".join(comps[name])):
+            if callee in comps and callee not in seen:
+                seen.add(callee)
+                closure(callee, seen)
+        return seen
+
+    bodies = re.findall(r" while\(.*?body=%?([\w.\-]+)", hlo)
+    texts = ["\n".join(line for c in closure(b, {b}) for line in comps[c])
+             for b in bodies]
+    inner = [t for t in texts if " while(" not in t]
+    assert len(inner) == 1, len(inner)
+    return inner[0]
+
+
+def _vertex_op_lines(text: str, op: str, dims) -> list:
+    """HLO lines of ``op`` whose shapes carry one of ``dims``."""
+    import re
+
+    return [line for line in text.splitlines() if f" {op}(" in line
+            and any(int(d) in dims
+                    for shape in re.findall(r"\[([\d,]+)\]", line)
+                    for d in shape.split(","))]
+
+
+def test_dense_bucket_solver_builds_what_once_per_newton_step(one_chip):
+    """The serve bucket lane at the stream cell's bucket (n_b 128, nnz
+    2048, k 4, batch 8) as the chip compiles it: no gather over the
+    vertex or edge axis, no scatter in any loop, and no power over the
+    (8, 4, 128, 128) pairwise tile in the tCG loop (W-hat is built once
+    per Newton step, not re-derived inside each tCG step's product
+    fusion)."""
+    from repro.core import PSCConfig
+    from repro.serve import psc_engine
+    from repro.serve.bucketing import BucketSpec
+
+    n, nnz, b = 128, 2048, 8
+    cfg = PSCConfig(k=K, reorder="none", newton_iters=20, tcg_iters=12)
+    solver, _ = psc_engine._bucket_solver(
+        BucketSpec(n=n, nnz=nnz, k=K, mode="cold"), cfg)
+    S = lambda shape, dt: _sds(one_chip, shape, dt)
+    hlo = solver.lower(S((b, nnz), jnp.int32), S((b, nnz), jnp.int32),
+                       S((b, nnz), jnp.float32), S((b, n), jnp.float32),
+                       S((b, n, K), jnp.float32)).compile().as_text()
+    tcg = _innermost_loop_text(hlo)
+    assert _vertex_op_lines(hlo, "gather", (n, nnz)) == []
+    assert hlo.count(" scatter(") == 1
+    assert " scatter(" not in tcg
+    tile_pows = [line for line in tcg.splitlines()
+                 if "f32[8,4,128,128]" in line and " power(" in line]
+    assert tile_pows == []

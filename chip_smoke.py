@@ -54,15 +54,10 @@ MXM_RTOL = 1e-4          # auto (or dist) vs coo, max-abs error / max-abs
 RCUT_TOL = 0.05          # solve vs coo solve: rcut <= ref * (1 + tol)
 PLANTED_TOL = 0.02       # planted-partition families: rcut <= planted*(1+tol)
 P_TARGET = 1.4
-# SELL-C-σ slice widths rounded up to a multiple of 8: each width run
-# is one slot loop in every compiled program that applies the matrix,
-# so compile time grows with the run count; on the hub graph of phase b
-# this keeps 7 runs for 18% more stored slots
-SELL_W_ALIGN = 8
 # refine only the finest V-cycle level: every refined level compiles
 # its own Newton step (tens of seconds each on the TPU), and the third
-# level (~29% of the vertices) carries SELL-C-σ with over a hundred
-# width runs, a compile of minutes
+# level (~29% of the vertices) carries SELL-C-σ, whose width runs each
+# add a slot loop to that compile
 REFINE_TOP_FRAC = 0.6
 
 
@@ -325,8 +320,7 @@ def _hub_sbm(block, hubs, hub_deg, seed=0):
     vals = np.concatenate([v, np.full(2 * keep.sum(), 2.0)])
     n = 4 * block
     _, first = np.unique(rows.astype(np.int64) * n + cols, return_index=True)
-    W = SparseMatrix.from_coo(rows[first], cols[first], vals[first], (n, n),
-                              sell_w_align=SELL_W_ALIGN)
+    W = SparseMatrix.from_coo(rows[first], cols[first], vals[first], (n, n))
     return W, truth
 
 

@@ -54,6 +54,12 @@ def _ortho(X):
     return Q
 
 
+def _mm(a, b):
+    """A dense product of :func:`lobpcg`'s block at full float32
+    precision (the SpMM ``matvec`` is elementwise and keeps its own)."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def lobpcg(matvec: Callable, X0: jnp.ndarray, k: int,
            precond_diag: Optional[jnp.ndarray] = None,
            max_iters: int = 200, tol: float = 1e-6) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -83,12 +89,15 @@ def lobpcg(matvec: Callable, X0: jnp.ndarray, k: int,
         blocks = [X, R] + ([P] if with_p else [])
         S = _ortho(jnp.concatenate(blocks, axis=1))
         AS = matvec(S)
-        T = S.T @ AS
+        # Rayleigh-Ritz in full float32: at the TPU's default precision
+        # (one bfloat16 pass) the Ritz values sit whole gaps away from
+        # the eigenvalues and the start of the continuation is lost
+        T = _mm(S.T, AS)
         T = 0.5 * (T + T.T)
         evals, V = jnp.linalg.eigh(T)
-        Xn = S @ V[:, :m]
+        Xn = _mm(S, V[:, :m])
         # P = component of the update living outside the X block
-        Pn = S[:, m:] @ V[m:, :m]
+        Pn = _mm(S[:, m:], V[m:, :m])
         return Xn, Pn, evals[:m], resnorm
 
     evals = jnp.zeros(m)

@@ -36,6 +36,16 @@ def _needs_static_p(cfg, W, U0) -> bool:
     return registry.backend_bakes_ring_params(cfg, W, probes)
 
 
+def ehvp_at(W, p, eps, hvp_mode, desc):
+    """U -> (eta -> Euclidean Hess F_p(U) @ eta): the HVP the driver
+    hands ``rtr_minimize``, called once per Newton step and applied at
+    each tCG step (``hvp_mode`` "graphblas": Algorithm 1 through
+    ``plap.hess_eta_graphblas``; else ``plap.hess_eta_matrix_free``)."""
+    h = (plap.hess_eta_graphblas if hvp_mode == "graphblas"
+         else plap.hess_eta_matrix_free)
+    return lambda U: partial(h, W, U, p=p, eps=eps, desc=desc)
+
+
 def _jitted_minimize(cfg, p, W, U0):
     """The jitted per-p trust-region minimization, memoized per
     (backend, interpret, hvp_mode, eps, iteration budget[, p]).  W rides
@@ -55,14 +65,8 @@ def _jitted_minimize(cfg, p, W, U0):
             registry.mark_trace(key)
             f = lambda U: plap.value(W, U, p_run, eps, desc=desc)
             g = lambda U: plap.euc_grad(W, U, p_run, eps, desc=desc)
-            if hvp_mode == "graphblas":
-                h = lambda U, eta: plap.hess_eta_graphblas(W, U, eta, p_run,
-                                                           eps, desc=desc)
-            else:
-                h = lambda U, eta: plap.hess_eta_matrix_free(W, U, eta, p_run,
-                                                             eps, desc=desc)
-            return rtr_minimize(f, g, lambda U: partial(h, U), U0,
-                                max_iters=newton_iters,
+            return rtr_minimize(f, g, ehvp_at(W, p_run, eps, hvp_mode, desc),
+                                U0, max_iters=newton_iters,
                                 tcg_iters=tcg_iters, grad_tol=grad_tol)
 
         if static_p is None:

@@ -86,6 +86,5 @@ def reorder(W: SparseMatrix, method: str = "rcm"
         dtype=W.vals.dtype,
         build_sellcs=W.sell_cols is not None,
         sell_c=W.sell_c or 32,
-        sell_sigma=W.sell_sigma or None,
-        sell_w_align=W.sell_w_align)
+        sell_sigma=W.sell_sigma or None)
     return W2, perm, inv

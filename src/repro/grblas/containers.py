@@ -50,10 +50,19 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.obs import metrics as _obs_metrics
+from repro.obs import trace as _obs_trace
+
 # Auto-build / auto-dispatch threshold: when full-ELL padding would store
 # more than this multiple of nnz, from_coo builds the SELL-C-σ layout as
 # well and backend auto-selection prefers it over ELL (grblas.backends).
 SELLCS_AUTO_THRESHOLD = 4.0
+
+# Most width classes (so width runs, under the global σ-sort) a SELL-C-σ
+# layout builds.  Each run is one slot loop in every compiled program
+# that applies the matrix: on the TPU a program's compile time grows by
+# about a second per run.
+SELLCS_MAX_RUNS = 40
 
 # Largest vertex count handled as one dense (n, n) tile: the exact-eigh
 # path of lobpcg.smallest_eigvecs and the serve bucket lane's dense
@@ -82,6 +91,28 @@ class GraphFingerprint(NamedTuple):
     @property
     def pattern_key(self) -> tuple:
         return (self.n, self.nnz, self.pattern)
+
+
+def sell_width_classes(widths: np.ndarray) -> np.ndarray:
+    """Slice widths (>= 1) rounded up to width classes.
+
+    A class keeps the leading ``b`` + 1 bits of a width: w is rounded up
+    to a multiple of 2^max(0, floor(log2 w) - b), so widths up to
+    2^(b+1) stay exact and each octave above holds at most 2^b classes,
+    none more than 1/2^b above the widths it serves.  ``b`` is the
+    largest (finest) that leaves at most SELLCS_MAX_RUNS classes among
+    the widths given; a layout with that few distinct widths is kept
+    exact.  b = 0 (powers of two) gives at most 32 classes to widths
+    below 2^31, which a row's degree always is."""
+    widths = np.asarray(widths, np.int64)
+    if widths.size == 0:
+        return widths
+    octave = np.floor(np.log2(widths)).astype(np.int64)
+    for b in range(int(octave.max()), -1, -1):
+        step = np.left_shift(1, np.maximum(octave - b, 0))
+        classes = -(-widths // step) * step
+        if b == 0 or len(np.unique(classes)) <= SELLCS_MAX_RUNS:
+            return classes
 
 
 def _row_layout(rows, n_rows: int, nnz: int):
@@ -117,10 +148,6 @@ class SparseMatrix:
     # SELL-C-σ (optional) — see module docstring for the storage model
     sell_c: int = 0                 # slice height C (static)
     sell_sigma: int = 0             # sorting-window size σ (static)
-    sell_w_align: int = 1           # slice-width rounding (static): >1
-                                    # merges nearby widths into fewer
-                                    # runs (fewer kernel launches) at a
-                                    # small fill cost
     sell_n_pad: int = 0             # n_rows rounded up to a multiple of C
     sell_row0: Tuple[int, ...] = ()  # static first-row offset of each width run
     sell_perm: Optional[jnp.ndarray] = None     # (n_pad,) int32 pos -> orig row
@@ -141,7 +168,7 @@ class SparseMatrix:
                     self.dense)
         aux = (self.n_rows, self.n_cols, self.nnz, self.block_size,
                None if self.bsr_indptr is None else tuple(self.bsr_indptr.tolist()),
-               self.sell_c, self.sell_sigma, self.sell_w_align,
+               self.sell_c, self.sell_sigma,
                self.sell_n_pad, self.sell_row0)
         return children, aux
 
@@ -151,7 +178,7 @@ class SparseMatrix:
          bsr_row_ids, sell_perm, sell_inv, sell_cols, sell_vals,
          sell_scatter, dense) = children
         (n_rows, n_cols, nnz, block_size, indptr,
-         sell_c, sell_sigma, sell_w_align, sell_n_pad, sell_row0) = aux
+         sell_c, sell_sigma, sell_n_pad, sell_row0) = aux
         return cls(n_rows=n_rows, n_cols=n_cols, nnz=nnz, rows=rows, cols=cols,
                    vals=vals, ell_cols=ell_cols, ell_vals=ell_vals,
                    block_size=block_size,
@@ -159,7 +186,6 @@ class SparseMatrix:
                    bsr_indices=bsr_indices, bsr_blocks=bsr_blocks,
                    bsr_row_ids=bsr_row_ids,
                    sell_c=sell_c, sell_sigma=sell_sigma,
-                   sell_w_align=sell_w_align,
                    sell_n_pad=sell_n_pad, sell_row0=sell_row0,
                    sell_perm=sell_perm, sell_inv=sell_inv,
                    sell_cols=sell_cols, sell_vals=sell_vals,
@@ -171,8 +197,7 @@ class SparseMatrix:
                  build_ell: Optional[bool] = None, build_bsr: bool = False,
                  block_size: int = 128, dtype=jnp.float32,
                  build_sellcs: Optional[bool] = None, sell_c: int = 32,
-                 sell_sigma: Optional[int] = None,
-                 sell_w_align: int = 1) -> "SparseMatrix":
+                 sell_sigma: Optional[int] = None) -> "SparseMatrix":
         """``build_sellcs=None`` (auto) builds the SELL-C-σ layout exactly
         when full-ELL padding would exceed SELLCS_AUTO_THRESHOLD x nnz —
         the skewed-degree regime where the hub rows make ELL unusable.
@@ -213,8 +238,7 @@ class SparseMatrix:
             mat._build_bsr(rows, cols, vals, block_size, dtype)
         if build_sellcs and n_rows > 0:
             mat._build_sellcs(rows, cols, vals, sell_c, sell_sigma, dtype,
-                              w_align=sell_w_align, counts=counts,
-                              pos_in_row=pos_in_row)
+                              counts=counts, pos_in_row=pos_in_row)
         return mat
 
     @staticmethod
@@ -222,15 +246,13 @@ class SparseMatrix:
                    build_bsr: bool = False,
                    block_size: int = 128, dtype=jnp.float32,
                    build_sellcs: Optional[bool] = None, sell_c: int = 32,
-                   sell_sigma: Optional[int] = None,
-                   sell_w_align: int = 1) -> "SparseMatrix":
+                   sell_sigma: Optional[int] = None) -> "SparseMatrix":
         sp = sp.tocoo()
         return SparseMatrix.from_coo(sp.row, sp.col, sp.data, sp.shape,
                                      build_ell=build_ell, build_bsr=build_bsr,
                                      block_size=block_size, dtype=dtype,
                                      build_sellcs=build_sellcs, sell_c=sell_c,
-                                     sell_sigma=sell_sigma,
-                                     sell_w_align=sell_w_align)
+                                     sell_sigma=sell_sigma)
 
     # ---- layout builders (host-side) ----
     def _build_ell(self, rows, cols, vals, dtype, counts=None,
@@ -249,6 +271,8 @@ class SparseMatrix:
         ell_vals[rows, pos_in_row] = vals
         self.ell_cols = jnp.asarray(ell_cols)
         self.ell_vals = jnp.asarray(ell_vals)
+        _obs_metrics.DEFAULT.counter("grblas_layouts_built_total",
+                                     layout="ell").inc()
 
     def _build_bsr(self, rows, cols, vals, bs, dtype):
         n_rb = -(-self.n_rows // bs)
@@ -270,17 +294,35 @@ class SparseMatrix:
         self.bsr_blocks = jnp.asarray(blocks, dtype)
         self.bsr_row_ids = jnp.asarray(u_rb, jnp.int32)
         _ = keys
+        _obs_metrics.DEFAULT.counter("grblas_layouts_built_total",
+                                     layout="bsr").inc()
 
     def _build_sellcs(self, rows, cols, vals, C: int, sigma: Optional[int],
-                      dtype, w_align: int = 1, counts=None, pos_in_row=None):
+                      dtype, counts=None, pos_in_row=None):
+        """Build the SELL-C-σ layout (``_pack_sellcs``) inside a
+        ``grblas.build_sellcs`` span that records what was built."""
+        with _obs_trace.ACTIVE.span("grblas.build_sellcs", cat="grblas",
+                                    n=self.n_rows, nnz=len(vals)) as sp:
+            self._pack_sellcs(rows, cols, vals, C, sigma, dtype, counts,
+                              pos_in_row)
+            widths = [c.shape[1] for c in self.sell_cols]
+            sp.set(runs=len(widths),
+                   stored_slots=sum(c.size for c in self.sell_cols),
+                   classes=sorted(set(widths), reverse=True))
+        _obs_metrics.DEFAULT.counter("grblas_layouts_built_total",
+                                     layout="sellcs").inc()
+
+    def _pack_sellcs(self, rows, cols, vals, C: int, sigma: Optional[int],
+                     dtype, counts=None, pos_in_row=None):
         """SELL-C-σ: σ-window degree sort, C-row slices, per-slice padding.
 
         ``sigma=None`` sorts globally (maximum fill reduction; sound
         because the permutation is internal to the layout and undone on
-        output).  ``w_align`` rounds slice widths up — >1 merges nearby
-        widths into fewer runs (fewer kernel launches) at a small fill
-        cost.  Requires the COO triple sorted by (row, col), which
-        from_coo guarantees.
+        output).  Slice widths are rounded up to width classes
+        (``sell_width_classes``), so a skewed degree distribution builds
+        at most SELLCS_MAX_RUNS width runs under the global sort.
+        Requires the COO triple sorted by (row, col), which from_coo
+        guarantees.
         """
         if self.n_rows != self.n_cols:
             raise ValueError(
@@ -316,7 +358,7 @@ class SparseMatrix:
         deg_p = np.zeros(n_pad, np.int64)
         deg_p[:n] = counts[perm]
         slice_w = deg_p.reshape(n_slices, C).max(axis=1)
-        slice_w = np.maximum(-(-slice_w // w_align) * w_align, 1)
+        slice_w = sell_width_classes(np.maximum(slice_w, 1))
 
         # contiguous runs of equal-width slices (slice "pointers")
         run_bounds = np.concatenate(
@@ -357,7 +399,6 @@ class SparseMatrix:
         perm_pad[:n] = perm                   # phantom rows read X[0]; their
         self.sell_c = C                       # stored vals are 0 so the
         self.sell_sigma = sigma_eff           # contribution annihilates
-        self.sell_w_align = max(int(w_align), 1)
         self.sell_n_pad = n_pad
         self.sell_row0 = tuple(run_row0)
         self.sell_perm = jnp.asarray(perm_pad, jnp.int32)
@@ -398,7 +439,6 @@ class SparseMatrix:
             vext = jnp.concatenate([vals, pad], axis=0)   # slot nnz == pad 0
             m.sell_c = self.sell_c
             m.sell_sigma = self.sell_sigma
-            m.sell_w_align = self.sell_w_align
             m.sell_n_pad = self.sell_n_pad
             m.sell_row0 = self.sell_row0
             m.sell_perm = self.sell_perm

@@ -34,3 +34,72 @@ def test_eigvec_orthonormal():
     _, evecs = lobpcg.smallest_eigvecs(W, 3, seed=1, max_iters=200)
     G = np.asarray(evecs.T @ evecs)
     np.testing.assert_allclose(G, np.eye(3), atol=1e-5)
+
+
+def _dot_precisions(jaxpr) -> list:
+    """The ``precision`` of every dot_general in a jaxpr, sub-jaxprs
+    (loop bodies, pjit calls) included."""
+    import jax
+
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(eqn.params["precision"])
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    out += _dot_precisions(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    out += _dot_precisions(sub)
+    return out
+
+
+def _laplacian(seed=0):
+    W, _ = delaunay_graph(11, seed=seed)
+    return W, lobpcg.laplacian_matvec(W)
+
+
+def test_lobpcg_rayleigh_ritz_runs_at_highest_precision(monkeypatch):
+    """Every dense product of the host-loop solver's step (Rayleigh-Ritz
+    T = S^T A S, the Ritz vectors S V and the P block) is a HIGHEST
+    precision dot: one bfloat16 pass loses stage 1 on the TPU."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    steps = []
+    real_jit = jax.jit
+
+    def spy(fun, **kw):
+        if getattr(fun, "__name__", "") == "step":
+            steps.append(fun)
+        return real_jit(fun, **kw)
+
+    monkeypatch.setattr(jax, "jit", spy)
+    W, mv = _laplacian()
+    X0 = jax.random.normal(jax.random.PRNGKey(0), (W.n_rows, 8), jnp.float32)
+    lobpcg.lobpcg(mv, X0, 4, max_iters=2)
+    monkeypatch.setattr(jax, "jit", real_jit)
+    assert len(steps) == 1
+    for with_p in (False, True):
+        jaxpr = jax.make_jaxpr(functools.partial(steps[0], with_p=with_p))(
+            X0, X0).jaxpr
+        precs = _dot_precisions(jaxpr)
+        assert len(precs) >= 3
+        highest = (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
+        assert all(p == highest for p in precs), precs
+
+
+def test_lobpcg_fixed_keeps_default_precision():
+    """The serve engine's traceable solver is left at the default matmul
+    precision: its compiled bucket programs stay what they were."""
+    import jax
+    import jax.numpy as jnp
+
+    W, mv = _laplacian()
+    X0 = jax.random.normal(jax.random.PRNGKey(0), (W.n_rows, 8), jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda X: lobpcg.lobpcg_fixed(mv, X, 4, iters=3))(X0).jaxpr
+    precs = _dot_precisions(jaxpr)
+    assert len(precs) >= 6 and all(p is None for p in precs), precs

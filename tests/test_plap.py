@@ -44,6 +44,18 @@ def test_hvp_matches_autodiff(setup, p, mode):
                                rtol=1e-5, atol=1e-8)
 
 
+@pytest.mark.parametrize("mode", ["graphblas", "matrix_free"])
+def test_newton_driver_hvp_is_the_mode_hvp(setup, mode):
+    from repro.core.solvers.newton import ehvp_at
+
+    W, U, eta = setup
+    fn = (plap.hess_eta_graphblas if mode == "graphblas"
+          else plap.hess_eta_matrix_free)
+    got = ehvp_at(W, 1.4, 1e-7, mode, None)(U)(eta)
+    want = fn(W, U, eta, 1.4, 1e-7)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_hvp_paths_agree(setup):
     W, U, eta = setup
     a = plap.hess_eta_graphblas(W, U, eta, 1.4, 1e-7)

@@ -15,6 +15,7 @@ from repro.grblas import (
     reals_ring,
 )
 from repro.grblas import api
+from repro.grblas.containers import SELLCS_MAX_RUNS, sell_width_classes
 
 
 def _rand(n=120, density=0.06, seed=0, **kw):
@@ -74,21 +75,46 @@ def test_sigma_windows_sort_locally_only():
 
 
 def test_w_align_merges_runs_and_stays_equivalent():
-    """sell_w_align > 1 rounds slice widths up: no more runs than the
-    tight build, every width a multiple of the alignment, same result."""
-    tight = _skewed(build_sellcs=True, sell_c=8)
-    merged = _skewed(build_sellcs=True, sell_c=8, sell_w_align=4)
-    assert merged.sell_w_align == 4
-    assert len(merged.sell_cols) <= len(tight.sell_cols)
-    assert all(c.shape[1] % 4 == 0 for c in merged.sell_cols)
-    X = jnp.ones((merged.n_rows, 3), jnp.float32)
+    """Width classes round slice widths up: no more runs than the tight
+    widths, every width at or above its slice's widest row and at most
+    a class step above it, the same product, and reorder rebuilds them
+    by the same rule."""
+    M = _skewed(build_sellcs=True, sell_c=8)
+    assert len(M.sell_cols) <= SELLCS_MAX_RUNS
+    widths = [c.shape[1] for c in M.sell_cols]
+    assert widths == sorted(widths, reverse=True)      # global σ-sort
+    deg = np.bincount(np.asarray(M.rows), minlength=M.n_rows)
+    slice_max = np.zeros(M.sell_n_pad, np.int64)
+    slice_max[:M.n_rows] = deg[np.asarray(M.sell_perm)[:M.n_rows]]
+    slice_max = slice_max.reshape(-1, M.sell_c).max(axis=1)
+    run_of_slice = np.repeat(widths, [c.shape[0] // M.sell_c
+                                      for c in M.sell_cols])
+    assert (run_of_slice >= slice_max).all()
+    assert (run_of_slice <= 2 * np.maximum(slice_max, 1)).all()
+    X = jnp.ones((M.n_rows, 3), jnp.float32)
     np.testing.assert_allclose(
-        np.asarray(mxm(merged, X, desc=Descriptor(backend="sellcs"))),
-        np.asarray(mxm(merged, X, desc=Descriptor(backend="coo"))),
+        np.asarray(mxm(M, X, desc=Descriptor(backend="sellcs"))),
+        np.asarray(mxm(M, X, desc=Descriptor(backend="coo"))),
         rtol=1e-4, atol=1e-4)
-    # reorder preserves the alignment parameter with the rest
     from repro.graphs import reorder
-    assert reorder(merged, "degree")[0].sell_w_align == 4
+    M2 = reorder(M, "degree")[0]
+    assert [c.shape for c in M2.sell_cols] == [c.shape for c in M.sell_cols]
+
+
+@pytest.mark.parametrize("widths,exact", [
+    (np.arange(1, 41), True),                 # 40 widths: kept exact
+    (np.arange(1, 42), False),                # 41: one bit coarser
+    (np.arange(1, 1025), False),
+    (np.array([3, 3, 7, 1000, 2 ** 30]), True)])
+def test_sell_width_classes_rule(widths, exact):
+    got = sell_width_classes(widths)
+    assert bool((got == widths).all()) is exact
+    assert len(np.unique(got)) <= SELLCS_MAX_RUNS
+    # monotone (runs stay contiguous under the global sort), never
+    # below a width, and less than one power-of-two step above it
+    order = np.argsort(widths, kind="stable")
+    assert (np.diff(got[order]) >= 0).all()
+    assert (got >= widths).all() and (got < 2 * widths).all()
 
 
 def test_auto_build_and_auto_dispatch_on_skew():
@@ -237,3 +263,93 @@ def test_newton_continuation_traces_once():
     cache_size = getattr(fn, "_cache_size", lambda: None)()
     if cache_size is not None:           # jax.jit cache stats, if exposed
         assert cache_size == 1
+
+
+def _lfr(n=4096, k_max=256, seed=0):
+    """A seeded LFR-style planted partition (power-law degrees, tau1 2,
+    mean degree 20, mu 0.2, 4 communities) from the benchmark's own
+    generator: hubs of up to ``k_max`` neighbours."""
+    import sys
+    from pathlib import Path
+
+    bench = str(Path(__file__).resolve().parents[1] / "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import graphs_lfr
+
+    r, c, v, truth = graphs_lfr.lfr_coo(n, 4, 2.0, 20.0, k_max, 0.2, seed)
+    return SparseMatrix.from_coo(r, c, v, (n, n)), truth
+
+
+@pytest.mark.parametrize("n,k_max,seed", [(4096, 256, 0),
+                                          (16384, 1024, 1)])
+def test_lfr_width_classes_bound_runs(n, k_max, seed):
+    """On the normal path (from_coo defaults) a power-law graph builds
+    SELL-C-σ only, in at most SELLCS_MAX_RUNS width runs, at most a
+    quarter above nnz in stored slots."""
+    W, _ = _lfr(n=n, k_max=k_max, seed=seed)
+    assert W.ell_cols is None and W.sell_cols is not None
+    assert len(W.sell_cols) <= SELLCS_MAX_RUNS
+    assert W.sellcs_fill_ratio() <= 1.25
+    tight = np.unique(np.bincount(np.asarray(W.rows), minlength=W.n_rows)
+                      [np.asarray(W.sell_perm)].reshape(-1, W.sell_c)
+                      .max(axis=1))
+    assert len(tight) > SELLCS_MAX_RUNS      # the rule had work to do
+
+
+@pytest.mark.parametrize("ring", ["reals", "plap_apply", "plap_hvp",
+                                  "multivalue"])
+def test_lfr_sellcs_rings_match_coo(ring):
+    """Each ring the flat solve dispatches, on the width-class layout
+    against the coo backend."""
+    from repro.grblas.semiring import (plap_edge_semiring,
+                                       plap_hvp_edge_semiring)
+
+    W, _ = _lfr()
+    rng = np.random.default_rng(3)
+    n, k = W.n_rows, 4
+    X = jnp.asarray(rng.standard_normal((n, k)), jnp.float32)
+    E = jnp.asarray(rng.standard_normal((n, k)), jnp.float32)
+    what = jnp.asarray(rng.uniform(0.5, 1.5, (W.nnz, k)), jnp.float32)
+    p, eps = 1.4, 1e-8
+    ops = {"reals": lambda d: mxm(W, X, reals_ring, desc=d),
+           "plap_apply": lambda d: mxm(W, X, plap_edge_semiring(p, eps),
+                                       desc=d),
+           "plap_hvp": lambda d: mxm(W, (X, E),
+                                     plap_hvp_edge_semiring(p, eps), desc=d),
+           "multivalue": lambda d: mxm(W.with_vals(what), X, reals_ring,
+                                       desc=d)}
+    got = np.asarray(ops[ring](Descriptor(backend="auto")))
+    want = np.asarray(ops[ring](Descriptor(backend="coo")))
+    assert api.available_backends(W, X)[0] == "sellcs"
+    np.testing.assert_allclose(got, want, rtol=2e-5,
+                               atol=2e-5 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_lfr_flat_auto_solve_matches_coo(seed):
+    """A whole flat solve (stage 1, one continuation level, k-means) on
+    the width-class SELL-C-σ layout (backend auto) against the same solve
+    on coo: F_p, RCut and the labels up to a permutation of the clusters.
+    The Newton budget is short on purpose: on these graphs the
+    trust-region path amplifies the last-bit differences of two
+    summation orders (float64 included), so the two solves drift apart
+    by 1e-5 in F_p after 30 Hessian applies and by 1e-3 after 200."""
+    from itertools import permutations
+
+    from repro.core import PSCConfig, p_spectral_cluster
+
+    W, _ = _lfr(seed=seed)
+    out = {}
+    for backend in ("auto", "coo"):
+        cfg = PSCConfig(k=4, p_target=1.8, newton_iters=3, tcg_iters=4,
+                        backend=backend, seed=0)
+        out[backend] = p_spectral_cluster(W, cfg)
+    a, b = out["auto"], out["coo"]
+    assert a.hvp_counts == b.hvp_counts
+    np.testing.assert_allclose(a.init_rcut, b.init_rcut, rtol=1e-6)
+    np.testing.assert_allclose(a.fvals[-1], b.fvals[-1], rtol=1e-5)
+    np.testing.assert_allclose(a.rcut, b.rcut, rtol=1e-5)
+    agree = max(int(np.sum(a.labels == np.asarray(perm)[b.labels]))
+                for perm in permutations(range(4)))
+    assert agree == W.n_rows
